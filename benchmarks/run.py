@@ -13,7 +13,6 @@ from __future__ import annotations
 
 import sys
 import time
-import traceback
 
 from benchmarks import (adapter_quality, multi_adapter, rapid_switching,
                         roofline, train_efficiency)
@@ -34,11 +33,7 @@ def main() -> None:
             continue
         print(f"\n### {name} " + "#" * (60 - len(name)))
         t0 = time.time()
-        try:
-            fn()
-        except Exception:  # noqa: BLE001 — keep the harness running
-            traceback.print_exc()
-            print(f"{name},ERROR")
+        fn()          # a failing section ends the run with a nonzero code
         print(f"### {name} done in {time.time() - t0:.0f}s")
 
 

@@ -182,9 +182,8 @@ def materialize_sharded(params, values, indices, pspecs, mesh,
         ispec = P(spec[0] if len(spec) > 0 else None,
                   spec[1] if len(spec) > 1 else None,
                   spec[2] if len(spec) > 2 else None, None)
-        from repro.compat import shard_map
-        return shard_map(local, mesh=mesh, in_specs=(spec, ispec, ispec),
-                         out_specs=spec, check_vma=False)(w, i, v)
+        return jax.shard_map(local, mesh=mesh, in_specs=(spec, ispec, ispec),
+                             out_specs=spec, check_vma=False)(w, i, v)
 
     return jax.tree.map(leaf, params, values, indices, pspecs,
                         is_leaf=lambda x: x is None)

@@ -38,9 +38,8 @@ def _scatter_kernel(counts_ref, alpha_ref, rows_ref, cols_ref, vals_ref,
                 r = rows_ref[0, 0, u]
                 c = cols_ref[0, 0, u]
                 v = vals_ref[0, 0, u]
-                cur = pl.load(out_ref, (pl.dslice(r, 1), pl.dslice(c, 1)))
-                pl.store(out_ref, (pl.dslice(r, 1), pl.dslice(c, 1)),
-                         cur + (alpha * v).astype(out_ref.dtype))
+                at = (pl.ds(r, 1), pl.ds(c, 1))
+                out_ref[at] = out_ref[at] + (alpha * v).astype(out_ref.dtype)
             return ()
 
         jax.lax.fori_loop(0, max_updates, body, ())

@@ -1,4 +1,4 @@
-"""sidedelta v2 — tiled, vectorised per-request sparse side-delta matmul.
+"""sidedelta v3 — tiled, vectorised per-request sparse side-delta matmul.
 
 Multi-tenant SHiRA serving keeps ONE shared copy of the base weights and
 gives every request in a batch its own adapter. Instead of patching the
@@ -13,22 +13,26 @@ adapter a = ids[b],
 
   delta[b, :, cols[a, k]] += x[b, :, rows[a, k]] * vals[a, k]   for all k
 
-Design (v2 — compiled-mode):
+Design (v3 — compiled-mode):
 
-  * Grid = (B, m_tiles): the output is m-tiled into (S, bm) blocks so that
-    large d_ff fits VMEM — v1's single (S, m) output block made compiled
-    execution infeasible for real MLP widths. ``plan_tiles`` picks (bm, kc)
-    from (S, n, m, K) under a VMEM byte budget.
-  * Vectorised body: no per-nonzero scalar stores. The K input columns are
-    gathered as an (S, K) block with a one-hot matmul
-    (x (S, n) @ onehot(rows) (n, K)), scaled by ``vals`` once, and cached
-    in VMEM scratch that persists across the m-tile loop (recomputed only
-    when the batch index changes, i.e. at m-tile 0). Each m-tile then
-    scatter-accumulates with a second one-hot/segment-sum matmul
-    ((S, K) @ onehot(cols - tile_start) (K, bm)); nonzeros whose column
+  * Grid = (B, S tiles, K chunks). Program (b, s, k) takes the k-th chunk
+    of ``kc`` nonzeros of request b's adapter and adds its contribution to
+    the (bs, m) output block of S tile s, which stays resident in VMEM
+    across the K axis (the innermost, accumulating grid axis). VMEM per
+    program is therefore independent of K and of the prompt length:
+    ``s_block`` picks the S tile from the budget, ``plan_tiles`` picks
+    (bm, kc) for the rest.
+  * Vectorised body: no per-nonzero scalar stores. The chunk's input
+    columns are gathered with one-hot matmuls over n-tiles of x
+    (x (bs, bn) @ onehot(rows) (bn, kc)) and scaled by ``vals``; each
+    m-tile of the output then scatter-accumulates with a second one-hot
+    matmul ((bs, kc) @ onehot(cols - tile_start)^T). Nonzeros whose column
     falls outside the current tile produce an all-zero one-hot row, which
-    is exactly the required mask. Both matmuls run on the MXU; chunking
-    over K in steps of ``kc`` bounds the one-hot VMEM footprint.
+    is exactly the required mask. Each nonzero is gathered once per S
+    tile, and the gathered chunk serves every m-tile.
+  * Tables are laid out (A, 1, K) for the kernel so that a (1, kc) block
+    satisfies Mosaic's tiling rule (the last two block dims are divisible
+    by (8, 128) or equal to the array's).
   * int8 tables in VMEM: ``vals`` may be int8 with a per-adapter f32
     ``scale`` (scalar-prefetch operand); the kernel dequantises AFTER the
     DMA, inside VMEM, so adapter HBM at serve time shrinks ~4x vs f32
@@ -36,19 +40,18 @@ Design (v2 — compiled-mode):
     the index tables 2x on top.
   * ``ids`` and ``scale`` are scalar-prefetch operands
     (PrefetchScalarGridSpec): the BlockSpec index maps route program b to
-    *its adapter's* (rows, cols, vals) block — only the selected adapter's
-    K-entry table is DMA'd into VMEM, not the whole registry. ids[b] < 0
-    means "no adapter": the index map clamps to slot 0 and the kernel
-    zeroes the output block.
+    *its adapter's* (rows, cols, vals) chunk — only the selected adapter's
+    table is DMA'd into VMEM, not the whole registry. ids[b] < 0 means "no
+    adapter": the index map clamps to slot 0 and the kernel skips the work
+    and leaves the zeroed output block.
 
 Backends: ``interpret=True`` runs the Pallas interpreter (kernel-body
 emulation, any backend). ``interpret=False`` compiles — through Mosaic on
-TPU, and on non-TPU backends (where this jax has no compiled Pallas
-lowering) through ``_sidedelta_xla``, an XLA formulation of the *same* tile
-plan: identical (bm, kc) tiling, the same local-column masking, the same
-int8 dequant placement. That keeps the tiling/masking/dequant logic
-exercised by a genuinely compiled executable in CPU CI, guarding the shape
-bookkeeping against TPU-only lowering surprises.
+TPU, and elsewhere (jax has no compiled Pallas lowering for the CPU)
+through ``_sidedelta_xla``, an XLA formulation of the *same* (bm, kc)
+plan: the same local-column masking and the same int8 dequant placement.
+That keeps the tiling/masking/dequant logic exercised by a genuinely
+compiled executable in CPU CI.
 
 The delta accumulates in f32 regardless of the compute dtype (the caller
 adds it onto the base matmul's output), so batched multi-tenant serving
@@ -64,11 +67,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Default VMEM byte budget for one program's working set. TPU cores have
-# ~16 MB of VMEM; half is left for double-buffered DMA and the compiler.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+# Default VMEM byte budget for one program's working set. TPU v5e and v6e
+# cores have 128 MiB of VMEM; the plan keeps a quarter of it, and the
+# compiler is allowed ``_VMEM_HEADROOM`` more for its own scratch.
+DEFAULT_VMEM_BUDGET = 32 * 1024 * 1024
+_VMEM_HEADROOM = 16 * 1024 * 1024
 
 _LANE = 128          # TPU lane width: last-dim tile granularity
+_SUBLANE = 8         # TPU sublane count: second-to-last-dim granularity
+_MAX_BM = 1024       # largest scatter m-tile
+_MAX_KC = 512        # largest K chunk per grid step
+_MAX_BN = 512        # largest gather n-tile
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -101,14 +110,11 @@ def plan_cache_key(S: int, n: int, m: int, K: int,
 def plan_is_valid(S: int, n: int, m: int, K: int, bm: int, kc: int,
                   *, vmem_budget: int = DEFAULT_VMEM_BUDGET,
                   x_itemsize: int = 4) -> bool:
-    """A usable (bm, kc): lane-aligned, positive, and within the budget
-    (best-effort like ``plan_tiles``: the minimum plan is always valid)."""
+    """A usable (bm, kc): lane-aligned, positive, and within the budget."""
     if bm < _LANE or kc < _LANE or bm % _LANE or kc % _LANE:
         return False
-    if bm == _LANE and kc == _LANE:
-        return True                  # the floor plan_tiles itself falls to
-    return vmem_estimate(S, n, m, K, bm, kc,
-                         x_itemsize=x_itemsize) <= vmem_budget
+    return vmem_estimate(S, n, m, K, bm, kc, x_itemsize=x_itemsize,
+                         vmem_budget=vmem_budget) <= vmem_budget
 
 
 def install_plan_cache(plans: Dict[PlanKey, Tuple[int, int]],
@@ -132,17 +138,52 @@ def plan_cache() -> Dict[PlanKey, Tuple[int, int]]:
     return dict(_PLAN_CACHE)
 
 
+def _tile(dim_pad: int, cap: int) -> int:
+    """Largest multiple of 128 that divides ``dim_pad`` and is <= ``cap``."""
+    t = min(cap, dim_pad) // _LANE * _LANE
+    while dim_pad % t:
+        t -= _LANE
+    return t
+
+
+def s_block(S: int, n: int, m: int, *,
+            vmem_budget: int = DEFAULT_VMEM_BUDGET,
+            x_itemsize: int = 4) -> int:
+    """Rows of x per program (the S tile). The double-buffered x and output
+    blocks grow with it, so they get at most half the budget: all of S
+    when that fits, else equal tiles of a multiple of 8 rows (never fewer
+    than 8 — ``plan_tiles`` then reports whether the rest still fits)."""
+    n_pad = _round_up(max(n, 1), _tile(_round_up(max(n, 1), _LANE), _MAX_BN))
+    m_pad = _round_up(max(m, 1), _LANE)
+    per_row = 2 * (n_pad * x_itemsize + m_pad * 4)
+    cap = (vmem_budget // 2) // per_row
+    if S <= cap:
+        return S
+    cap = max(cap // _SUBLANE * _SUBLANE, _SUBLANE)
+    if S <= cap:
+        return S
+    tiles = -(-S // cap)
+    return _round_up(-(-S // tiles), _SUBLANE)
+
+
 def vmem_estimate(S: int, n: int, m: int, K: int, bm: int, kc: int,
-                  *, x_itemsize: int = 4, idx_itemsize: int = 4,
-                  val_itemsize: int = 4) -> int:
-    """Bytes one grid program keeps live in VMEM under the v2 tile plan."""
-    x_block = S * n * x_itemsize
-    xs_scratch = S * K * 4                      # gathered+scaled f32 cache
-    tables = K * (2 * idx_itemsize + val_itemsize)
-    onehot_gather = n * kc * 4                  # j == 0 only
-    onehot_scatter = kc * bm * 4
-    out_block = S * bm * 4
-    return (x_block + xs_scratch + tables + out_block
+                  *, x_itemsize: int = 4,
+                  vmem_budget: int = DEFAULT_VMEM_BUDGET) -> int:
+    """Bytes one grid program keeps live in VMEM under the v3 tile plan.
+    K does not enter: the tables stream through in ``kc`` chunks. The
+    budget does, through the S tile ``s_block`` picks under it."""
+    bs = s_block(S, n, m, vmem_budget=vmem_budget, x_itemsize=x_itemsize)
+    bn = _tile(_round_up(max(n, 1), _LANE), _MAX_BN)
+    n_pad = _round_up(max(n, 1), bn)
+    m_pad = _round_up(max(m, 1), bm)
+    blocks = 2 * bs * (n_pad * x_itemsize + m_pad * 4)   # x + out, 2 buffers
+    # a (1, kc) table block fills a whole sublane tile: 32 bytes per column
+    # whatever the dtype (8 x 4, 16 x 2, 32 x 1), three tables, 2 buffers
+    tables = 2 * 3 * 32 * kc
+    gathered = bs * kc * 4
+    onehot_gather = bn * kc * (4 + x_itemsize)     # iota compare + one-hot
+    onehot_scatter = bm * kc * 8 + bs * bm * 4     # ... + the partial tile
+    return (blocks + tables + gathered
             + max(onehot_gather, onehot_scatter))
 
 
@@ -151,12 +192,12 @@ def plan_tiles(S: int, n: int, m: int, K: int,
                x_itemsize: int = 4) -> Tuple[int, int]:
     """Pick (bm, kc) so one program's working set fits ``vmem_budget``.
 
-    bm is the output m-tile (multiple of 128, <= padded m); kc the K-chunk
-    both one-hot matmuls step by. Fixed costs (the x block, the (S, K)
-    scratch, the tables) are paid regardless; the free variables trade the
-    one-hot buffers against the budget remainder. Best-effort: if even the
-    minimum (128, 128) plan exceeds the budget the minimum is returned —
-    the caller wanted a kernel, not an exception.
+    bm is the scatter m-tile (multiple of 128 dividing the padded m); kc
+    the K chunk one grid step takes. The S tile's blocks are paid
+    regardless; the largest K chunk (then the largest m-tile) that fits
+    the remainder wins. Raises ``ValueError`` when not even the (128, 128)
+    plan fits: a kernel over budget would be refused by the compiler, or
+    run with less VMEM than its plan assumed.
 
     An autotuned plan cache (``install_plan_cache``, populated by
     ``analysis/autotune.py`` from measured step times) is consulted first;
@@ -173,19 +214,21 @@ def plan_tiles(S: int, n: int, m: int, K: int,
     plan_cache_stats["misses"] += 1
     m_pad = _round_up(max(m, 1), _LANE)
     K_pad = _round_up(max(K, 1), _LANE)
-    kc = min(K_pad, 512)
-    while True:
-        fixed = S * n * x_itemsize + S * K_pad * 4 + K_pad * 12 + n * kc * 4
-        room = max(vmem_budget - fixed, 0)
-        # per-bm cost: out block (S rows) + scatter one-hot (kc rows), f32
-        bm = (room // ((S + kc) * 4)) // _LANE * _LANE
-        bm = max(min(bm, m_pad), _LANE)
-        while bm > _LANE and m_pad % bm:
-            bm -= _LANE             # keep the grid exact: bm | padded m
-        if kc <= _LANE or vmem_estimate(S, n, m, K, bm, kc,
-                                        x_itemsize=x_itemsize) <= vmem_budget:
-            return int(bm), int(kc)
-        kc -= _LANE                 # fixed costs too big: smaller K chunk
+    for kc in range(min(K_pad, _MAX_KC), 0, -_LANE):
+        bm = _tile(m_pad, _MAX_BM)
+        while True:
+            if vmem_estimate(S, n, m, K, bm, kc, x_itemsize=x_itemsize,
+                             vmem_budget=vmem_budget) <= vmem_budget:
+                return int(bm), int(kc)
+            if bm == _LANE:
+                break
+            bm = _tile(m_pad, bm - _LANE)
+    floor = vmem_estimate(S, n, m, K, _LANE, _LANE, x_itemsize=x_itemsize,
+                          vmem_budget=vmem_budget)
+    raise ValueError(
+        f"sidedelta: no tile plan fits a {vmem_budget}-byte VMEM budget "
+        f"for S={S}, n={n}, m={m} (x itemsize {x_itemsize}); the smallest "
+        f"needs {floor} bytes")
 
 
 # ---------------------------------------------------------------------------
@@ -193,49 +236,46 @@ def plan_tiles(S: int, n: int, m: int, K: int,
 # ---------------------------------------------------------------------------
 
 def _sidedelta_kernel(ids_ref, scale_ref, x_ref, rows_ref, cols_ref,
-                      vals_ref, out_ref, xs_ref, *, n: int, bm: int, kc: int,
-                      nchunks: int):
+                      vals_ref, out_ref, *, bn: int, bm: int, precision):
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    slot = jnp.maximum(ids_ref[b], 0)
-    sc = scale_ref[slot]
+    aid = ids_ref[b]
 
-    @pl.when(j == 0)
-    def _gather():
-        # xs[:, k] = x[:, rows[k]] * vals[k] * scale — cached for every
-        # m-tile of this request (the grid iterates j innermost).
-        xb = x_ref[0].astype(jnp.float32)                      # (S, n)
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-        def chunk(i, _):
-            sl = (pl.dslice(0, 1), pl.dslice(i * kc, kc))
-            r = pl.load(rows_ref, sl)[0].astype(jnp.int32)     # (kc,)
-            v = pl.load(vals_ref, sl)[0].astype(jnp.float32) * sc
-            onehot = (jax.lax.broadcasted_iota(jnp.int32, (n, kc), 0)
-                      == r[None, :]).astype(jnp.float32)
-            xg = jax.lax.dot_general(
-                xb, onehot, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)            # (S, kc)
-            pl.store(xs_ref, (slice(None), pl.dslice(i * kc, kc)),
-                     xg * v[None, :])
+    @pl.when(aid >= 0)
+    def _accumulate():
+        bs = x_ref.shape[0]
+        kc = rows_ref.shape[-1]
+        r = rows_ref[...].astype(jnp.int32)                    # (1, kc)
+        c = cols_ref[...].astype(jnp.int32)
+        v = vals_ref[...].astype(jnp.float32) * scale_ref[aid]
+
+        def gather(i, acc):
+            # xs[:, k] += x[:, rows[k]] for the rows inside this n-tile
+            n0 = pl.multiple_of(i * bn, bn)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, (bn, kc), 0) + n0
+                      == r).astype(x_ref.dtype)
+            return acc + jax.lax.dot_general(
+                x_ref[:, pl.ds(n0, bn)], onehot, (((1,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+
+        xs = jax.lax.fori_loop(0, x_ref.shape[1] // bn, gather,
+                               jnp.zeros((bs, kc), jnp.float32)) * v
+
+        def scatter(j, _):
+            # nonzeros outside this m-tile get an all-zero one-hot row: the
+            # segment-sum matmul masks them for free
+            m0 = pl.multiple_of(j * bm, bm)
+            onehot = (jax.lax.broadcasted_iota(jnp.int32, (bm, kc), 0) + m0
+                      == c).astype(jnp.float32)
+            out_ref[:, pl.ds(m0, bm)] += jax.lax.dot_general(
+                xs, onehot, (((1,), (1,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
             return ()
 
-        jax.lax.fori_loop(0, nchunks, chunk, ())
-
-    def chunk(i, acc):
-        sl = (pl.dslice(0, 1), pl.dslice(i * kc, kc))
-        local = pl.load(cols_ref, sl)[0].astype(jnp.int32) - j * bm
-        xs = pl.load(xs_ref, (slice(None), pl.dslice(i * kc, kc)))
-        # nonzeros outside this m-tile get an all-zero one-hot row: the
-        # segment-sum matmul masks them for free.
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (kc, bm), 1)
-                  == local[:, None]).astype(jnp.float32)
-        return acc + jax.lax.dot_general(
-            xs, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    acc = jax.lax.fori_loop(0, nchunks, chunk,
-                            jnp.zeros((xs_ref.shape[0], bm), jnp.float32))
-    out_ref[0] = acc * (ids_ref[b] >= 0).astype(jnp.float32)
+        jax.lax.fori_loop(0, out_ref.shape[1] // bm, scatter, ())
 
 
 # ---------------------------------------------------------------------------
@@ -318,33 +358,43 @@ def sidedelta_rows(x: jax.Array, rows: jax.Array, cols: jax.Array,
         vals = jnp.pad(vals, pad)
     if interpret == "xla" or (
             not interpret and jax.default_backend() != "tpu"):
-        # this jax has no compiled Pallas path off-TPU: run the same tile
-        # plan through XLA so compiled-mode CI still exercises it
+        # jax has no compiled Pallas path off-TPU: run the same tile plan
+        # through XLA so compiled-mode CI still exercises it
         return _sidedelta_xla(x, rows, cols, vals, scale, ids, m, bm, kc)
-    mt = m_pad // bm
-    kernel = functools.partial(_sidedelta_kernel, n=n, bm=bm, kc=kc,
-                               nchunks=K_pad // kc)
+    isize = x.dtype.itemsize
+    bs = s_block(S, n, m, vmem_budget=vmem_budget, x_itemsize=isize)
+    bn = _tile(_round_up(n, _LANE), _MAX_BN)
+    S_pad, n_pad = _round_up(S, bs), _round_up(n, bn)
+    if (S_pad, n_pad) != (S, n):
+        x = jnp.pad(x, ((0, 0), (0, S_pad - S), (0, n_pad - n)))
+    # exact gathers: a bf16 one-hot picks bf16 activations exactly at the
+    # default precision; f32 activations (parity runs) need all the passes
+    precision = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+                 else None)
+    kernel = functools.partial(_sidedelta_kernel, bn=bn, bm=bm,
+                               precision=precision)
 
-    def slot_map(b, j, ids, scale):
-        return (jnp.maximum(ids[b], 0), 0)
+    def table_map(b, s, k, ids, scale):
+        return (jnp.maximum(ids[b], 0), 0, k)
 
+    def row_map(b, s, k, ids, scale):
+        return (b, s, 0)
+
+    table = pl.BlockSpec((None, 1, kc), table_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, mt),
-        in_specs=[
-            pl.BlockSpec((1, S, n), lambda b, j, ids, scale: (b, 0, 0)),
-            pl.BlockSpec((1, K_pad), slot_map),
-            pl.BlockSpec((1, K_pad), slot_map),
-            pl.BlockSpec((1, K_pad), slot_map),
-        ],
-        out_specs=pl.BlockSpec((1, S, bm),
-                               lambda b, j, ids, scale: (b, 0, j)),
-        scratch_shapes=[pltpu.VMEM((S, K_pad), jnp.float32)],
+        grid=(B, S_pad // bs, K_pad // kc),
+        in_specs=[pl.BlockSpec((None, bs, n_pad), row_map),
+                  table, table, table],
+        out_specs=pl.BlockSpec((None, bs, m_pad), row_map),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, S, m_pad), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, S_pad, m_pad), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_budget + _VMEM_HEADROOM),
         interpret=interpret,
-    )(ids, scale, x, rows, cols, vals)
-    return out[..., :m]
+    )(ids, scale, x, *(t.reshape(A, 1, K_pad) for t in (rows, cols, vals)))
+    return out[:, :S, :m]

@@ -19,13 +19,10 @@ import jax
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    jax.sharding.AxisType) only exist on newer releases; older ones default
-    to Auto axes anyway, which is what we want."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axes (the sharding rules rely on the
+    compiler propagating shardings between annotated points)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from repro import core
 from repro.configs import AdapterConfig, get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 
 
@@ -168,6 +169,7 @@ def main() -> None:
                     help="int8 adapters: quantized store packs (continuous) "
                     "and int8 device-side delta tables (both paths)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.encoder_only:

@@ -1,7 +1,7 @@
 """Training driver.
 
-Runs real training on this host (reduced/smoke configs — the container is
-CPU-only) or lowers the production step for a mesh (``--dryrun``-style use
+Runs real training on this host's default device (reduced/smoke configs
+on a CPU) or lowers the production step for a mesh (``--dryrun``-style use
 should go through repro.launch.dryrun instead).
 
 Examples:
@@ -18,6 +18,7 @@ import os
 from repro.configs import (AdapterConfig, ModelConfig, RunConfig, TrainConfig,
                            get_config, get_smoke_config)
 from repro.configs.base import ShapeSpec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import Trainer
 from repro.runtime.trainer import TrainerConfig
 
@@ -60,6 +61,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--out", default=None, help="write loss history JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.preset == "100m":
         cfg = PRESET_100M

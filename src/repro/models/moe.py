@@ -206,8 +206,7 @@ def _moe_ffn_ep(params: dict, cfg: ModelConfig, x: jax.Array,
     specs_in = (P(), P("model", None, None), P("model", None, None),
                 P("model", None, None), P(b_spec, None, None))
     specs_out = (P(b_spec, None, None), P())
-    from repro.compat import shard_map
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh, in_specs=specs_in, out_specs=specs_out,
         check_vma=False,
     )(params["w_router"], params["experts_w_up"], params["experts_w_gate"],

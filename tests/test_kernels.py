@@ -209,15 +209,19 @@ def test_sidedelta_int8_tables(interpret):
 
 def test_sidedelta_plan_tiles_budget():
     """The VMEM helper must respect its budget knob: a tighter budget
-    yields a smaller m-tile, never a plan that misses the grid."""
+    yields a smaller m-tile, never a plan that misses the grid, and a
+    budget no plan fits raises instead of returning an over-budget one."""
     from repro.kernels.sidedelta import plan_tiles, vmem_estimate
     S, n, m, K = 16, 512, 16384, 2048
     big_bm, big_kc = plan_tiles(S, n, m, K, vmem_budget=8 << 20)
     small_bm, small_kc = plan_tiles(S, n, m, K, vmem_budget=2 << 20)
     assert small_bm <= big_bm
-    assert vmem_estimate(S, n, m, K, small_bm, small_kc) <= 2 << 20
+    assert vmem_estimate(S, n, m, K, small_bm, small_kc,
+                         vmem_budget=2 << 20) <= 2 << 20
     for bm in (big_bm, small_bm):
         assert bm % 128 == 0 and (-(-m // 128) * 128) % bm == 0
+    with pytest.raises(ValueError, match="no tile plan fits"):
+        plan_tiles(S, n, m, K, vmem_budget=256 << 10)
 
 
 def test_sidedelta_table_roundtrip():

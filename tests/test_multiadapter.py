@@ -192,6 +192,27 @@ def test_sparse_adamw_batched_matches_adamw_math():
                                    rtol=1e-6, atol=1e-6)
 
 
+def test_sparse_adamw_batched_ragged_row_blocks():
+    """More rows than one row block, and not a multiple of it: the ragged
+    last block updates its rows exactly like the full ones."""
+    from repro.kernels import ops, ref
+    from repro.kernels.sparse_adamw import _ROW_BLOCK
+    rng = np.random.default_rng(3)
+    R, K = 2 * _ROW_BLOCK + 5, 256
+    v = jnp.asarray(rng.standard_normal((R, K)), jnp.float32)
+    g = jnp.asarray(rng.standard_normal((R, K)), jnp.float32)
+    m = jnp.asarray(rng.standard_normal((R, K)), jnp.float32) * 0.1
+    u = jnp.asarray(np.abs(rng.standard_normal((R, K))), jnp.float32) * 0.01
+    got = ops.sparse_adamw_batched(v, g, m, u, jnp.int32(2),
+                                   lr=jnp.float32(1e-2), wd=0.1, block=128,
+                                   interpret=True)
+    want = ref.sparse_adamw_ref(v, g, m, u, lr=1e-2, b1=0.9, b2=0.999,
+                                eps=1e-8, wd=0.1, step=2)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
 def test_sparse_adamw_batched_int8_dequant_in_kernel():
     """The kernel's inline int8 dequant == decode-then-update reference."""
     from repro.kernels import ops

@@ -364,8 +364,9 @@ def test_int8_tables_skip_f32_roundtrip(dense_setup):
 
 def test_forced_compiled_mode_on_cpu(dense_setup):
     """interpret=False threaded through the engine -> pdot -> kernel must
-    serve correctly under JAX_PLATFORMS=cpu (the compiled tile-plan
-    dispatch) and match the default interpret-mode engine exactly."""
+    serve correctly under JAX_PLATFORMS=cpu (jax compiles Pallas only for
+    accelerators, so this runs the kernel's XLA twin) and match the default
+    interpret-mode engine exactly."""
     with layers.compute_precision(jnp.float32):
         cfg, params, packs = dense_setup
         default = MultiTenantEngine(cfg, params)

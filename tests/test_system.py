@@ -85,3 +85,27 @@ def test_pack_size_comparable_to_lora(adapters_and_base):
     pack_bytes = packs[1].nbytes()
     model_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(base))
     assert pack_bytes < 0.35 * model_bytes
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_entry_point_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The entry points' compile cache follows JAX_COMPILATION_CACHE_DIR
+    and sets nothing else; without it, a fixed directory in the checkout."""
+    from pathlib import Path
+    from repro.launch.compile_cache import enable_compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    repo = Path(__file__).resolve().parents[1]
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = enable_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            assert got == str(repo / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
