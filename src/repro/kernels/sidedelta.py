@@ -153,15 +153,13 @@ def s_block(S: int, n: int, m: int, *,
     blocks grow with it, so they get at most half the budget: all of S
     when that fits, else equal tiles of a multiple of 8 rows (never fewer
     than 8 — ``plan_tiles`` then reports whether the rest still fits)."""
-    n_pad = _round_up(max(n, 1), _tile(_round_up(max(n, 1), _LANE), _MAX_BN))
+    n_pad = _round_up(max(n, 1), _LANE)
     m_pad = _round_up(max(m, 1), _LANE)
     per_row = 2 * (n_pad * x_itemsize + m_pad * 4)
     cap = (vmem_budget // 2) // per_row
-    if S <= cap:
+    if S <= max(cap, _SUBLANE):
         return S
     cap = max(cap // _SUBLANE * _SUBLANE, _SUBLANE)
-    if S <= cap:
-        return S
     tiles = -(-S // cap)
     return _round_up(-(-S // tiles), _SUBLANE)
 
@@ -173,8 +171,8 @@ def vmem_estimate(S: int, n: int, m: int, K: int, bm: int, kc: int,
     K does not enter: the tables stream through in ``kc`` chunks. The
     budget does, through the S tile ``s_block`` picks under it."""
     bs = s_block(S, n, m, vmem_budget=vmem_budget, x_itemsize=x_itemsize)
-    bn = _tile(_round_up(max(n, 1), _LANE), _MAX_BN)
-    n_pad = _round_up(max(n, 1), bn)
+    n_pad = _round_up(max(n, 1), _LANE)
+    bn = _tile(n_pad, _MAX_BN)
     m_pad = _round_up(max(m, 1), bm)
     blocks = 2 * bs * (n_pad * x_itemsize + m_pad * 4)   # x + out, 2 buffers
     # a (1, kc) table block fills a whole sublane tile: 32 bytes per column
@@ -363,8 +361,8 @@ def sidedelta_rows(x: jax.Array, rows: jax.Array, cols: jax.Array,
         return _sidedelta_xla(x, rows, cols, vals, scale, ids, m, bm, kc)
     isize = x.dtype.itemsize
     bs = s_block(S, n, m, vmem_budget=vmem_budget, x_itemsize=isize)
-    bn = _tile(_round_up(n, _LANE), _MAX_BN)
-    S_pad, n_pad = _round_up(S, bs), _round_up(n, bn)
+    S_pad, n_pad = _round_up(S, bs), _round_up(n, _LANE)
+    bn = _tile(n_pad, _MAX_BN)
     if (S_pad, n_pad) != (S, n):
         x = jnp.pad(x, ((0, 0), (0, S_pad - S), (0, n_pad - n)))
     # exact gathers: a bf16 one-hot picks bf16 activations exactly at the
