@@ -4,7 +4,6 @@
   multi_adapter     -> Table 4   (fusion interference, %Drop)
   rapid_switching   -> Fig. 5    (scatter vs fuse)
   train_efficiency  -> Table 6   (memory / step time per adapter)
-  roofline          -> EXPERIMENTS §Roofline (from dry-run artifacts)
 
 Each section prints CSV. Run everything:
   PYTHONPATH=src python -m benchmarks.run
@@ -15,14 +14,13 @@ import sys
 import time
 
 from benchmarks import (adapter_quality, multi_adapter, rapid_switching,
-                        roofline, train_efficiency)
+                        train_efficiency)
 
 SECTIONS = [
     ("rapid_switching", rapid_switching.main),
     ("train_efficiency", train_efficiency.main),
     ("adapter_quality", adapter_quality.main),
     ("multi_adapter", multi_adapter.main),
-    ("roofline", roofline.main),
 ]
 
 

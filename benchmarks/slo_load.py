@@ -133,8 +133,8 @@ def main() -> None:
                     "faults against the fault-free async run")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write the serving trace of the async run "
-                    "(JSONL; a .chrome.json twin is written next to it) "
-                    "and print replay attribution + overlap verification")
+                    "(JSONL) and print replay attribution + overlap "
+                    "verification")
     ap.add_argument("--plan-cache", nargs="?", const="benchmarks/"
                     "plan_cache.json", default=None, metavar="PATH",
                     help="install an autotuned sidedelta plan cache "
@@ -316,11 +316,8 @@ def main() -> None:
                        if e.get("ph") == "X"
                        and e.get("name") == "prefetch.stall") / 1e3
         jsonl = tracer.to_jsonl(args.trace)
-        chrome = tracer.to_chrome(
-            args.trace.rsplit(".jsonl", 1)[0] + ".chrome.json"
-            if args.trace.endswith(".jsonl") else args.trace + ".chrome.json")
         att = replay.attribute(tracer, wall_us=rep.wall_s * 1e6)
-        print(f"trace: {len(tracer)} events -> {jsonl} (+ {chrome}); "
+        print(f"trace: {len(tracer)} events -> {jsonl}; "
               f"spans cover {att['coverage']:.1%} of wall")
         for row in replay.critical_path(tracer, top=5):
             print(f"  {row['name']:<16} {row['self_us'] / 1e3:9.2f} ms "

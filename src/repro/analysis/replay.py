@@ -255,7 +255,7 @@ def _intersect_us(lo: float, hi: float,
 def verify_overlap(events: TraceLike, *,
                    async_names: Optional[Sequence[str]] = None,
                    under: Sequence[str] = ("decode", "prefill_chunk",
-                                           "admit"),
+                                           "admit", "sync"),
                    baseline: Optional[TraceLike] = None,
                    serial_names: Sequence[str] = ("disk_load",
                                                   "table_rebuild"),

@@ -17,7 +17,22 @@ Enable tracing by installing a tracer::
     ... serve ...
     trace.uninstall()
     tr.to_jsonl("run.trace.jsonl")          # one event per line
-    tr.to_chrome("run.trace.json")          # chrome://tracing / Perfetto
+
+The profiler is a second sink. While a ``jax.profiler`` session is
+recording, every span is also a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` with the span's args as metadata, whether or not a
+tracer is installed: the spans then sit on the host plane of the
+``.xplane.pb``, on the same clock as the device's executions and the
+benchmark's ``bench.*`` marks, and Perfetto shows them beside the device
+rows. With neither a tracer nor a session the hook is one cheap check
+and the null return. Three spans exist on the profiler side only, since
+the serving loop does not open them itself: ``repro.gc`` (a Python
+garbage collection, arg ``generation``, from a ``gc.callbacks`` hook),
+``repro.jaxpr`` (JAX tracing a function to a jaxpr, arg ``fun``) and
+``repro.compile`` (arg ``stage``: ``lower``, or ``backend`` for an XLA
+compile or a load from the persistent compile cache, which JAX does not
+annotate). The last two come from ``jax.monitoring``, which marks each
+stage's start with a scalar event and its end with a duration event.
 
 Event model (serving loop on one host thread, plus prefetch workers):
 
@@ -60,11 +75,14 @@ both what was injected and how serving absorbed it.
 """
 from __future__ import annotations
 
+import gc
 import json
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+import jax
 
 __all__ = ["Tracer", "install", "uninstall", "active", "enabled",
            "span", "instant", "counter"]
@@ -86,31 +104,68 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 _tracer: Optional["Tracer"] = None
+# True while a profiler session records (a check in native code, tens of
+# nanoseconds); a TraceAnnotation opened with none recording still costs
+# about a microsecond
+_recording = jax.profiler.TraceAnnotation.is_enabled
+
+
+def _annotation(name: str, args: dict):
+    return jax.profiler.TraceAnnotation("repro." + name, **args)
+
+
+class _ProfilerSpan:
+    """A span on the profiler's clock only: no tracer is installed."""
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, args: dict):
+        self._ann = _annotation(name, args)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **kw):
+        self._ann.set_metadata(**kw)
+        return self
 
 
 class _Span:
-    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_depth", "_tid")
+    __slots__ = ("_tr", "name", "cat", "args", "_t0", "_depth", "_tid",
+                 "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str, args: dict):
         self._tr = tr
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = None
 
     def set(self, **kw):
         """Attach args discovered while the span is open."""
         self.args.update(kw)
+        if self._ann is not None:
+            self._ann.set_metadata(**kw)
         return self
 
     def __enter__(self):
         tr = self._tr
         self._tid = tr._tid()
         self._depth = tr._enter_depth()
+        if _recording():
+            self._ann = _annotation(self.name, self.args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tr
         tr._exit_depth()
         tr._push({"ph": "X", "name": self.name, "cat": self.cat,
@@ -122,7 +177,7 @@ class _Span:
 
 
 class Tracer:
-    """Bounded in-memory event ring with JSONL / Chrome export."""
+    """Bounded in-memory event ring with JSONL export."""
 
     def __init__(self, capacity: int = 1 << 16):
         if capacity < 1:
@@ -207,32 +262,6 @@ class Tracer:
                 f.write("\n")
         return path
 
-    def to_chrome(self, path: str) -> str:
-        """Chrome trace-event JSON (load in chrome://tracing / Perfetto)."""
-        out = []
-        for ev in self.events():
-            ce = {"name": ev["name"], "cat": ev["cat"] or "serving",
-                  "ph": ev["ph"], "ts": ev["ts"], "pid": 0,
-                  "tid": ev.get("tid", 0), "args": ev["args"]}
-            if ev["ph"] == "X":
-                ce["dur"] = ev["dur"]
-            if ev["ph"] == "i":
-                ce["s"] = "t"
-            out.append(ce)
-        with open(path, "w") as f:
-            json.dump({"traceEvents": out}, f)
-        return path
-
-    def summary(self) -> Dict[str, Any]:
-        by_name: Dict[str, float] = {}
-        n_spans = 0
-        for ev in self._buf:
-            if ev["ph"] == "X":
-                n_spans += 1
-                by_name[ev["name"]] = by_name.get(ev["name"], 0.0) + ev["dur"]
-        return {"events": len(self._buf), "spans": n_spans,
-                "dropped": self.dropped, "dur_us_by_name": by_name}
-
 
 # ---------------------------------------------------------------------------
 # Module-level switchboard (what the instrumentation hooks call).
@@ -262,12 +291,15 @@ def enabled() -> bool:
 
 
 def span(name: str, /, cat: str = "serving", **args):
-    """Time a phase. No-op (returns a shared null context) when tracing
-    is off — safe to leave in hot serving loops. ``name``/``cat`` are
-    positional-only so span args may themselves be called ``name``."""
+    """Time a phase. No-op (returns a shared null context) when no tracer
+    is installed and no profiler session records — safe to leave in hot
+    serving loops. ``name``/``cat`` are positional-only so span args may
+    themselves be called ``name``."""
     t = _tracer
     if t is None:
-        return _NULL
+        if not _recording():
+            return _NULL
+        return _ProfilerSpan(name, args)
     return t.span(name, cat, args)
 
 
@@ -281,3 +313,62 @@ def counter(name: str, value: float, cat: str = "serving") -> None:
     t = _tracer
     if t is not None:
         t.counter(name, value, cat)
+
+
+# ---------------------------------------------------------------------------
+# Spans the serving thread does not open: garbage collection and compiles.
+# Profiler only — the ring buffer's reader (``replay``) models the serving
+# loop's own phases, and would count a collection on a prefetch worker as
+# prefetch work.
+# ---------------------------------------------------------------------------
+
+_gc_open = None         # collections neither nest nor overlap
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        if _recording():
+            _gc_open = _annotation("gc", {"generation": info["generation"]})
+            _gc_open.__enter__()
+    elif _gc_open is not None:
+        ann, _gc_open = _gc_open, None
+        ann.__exit__(None, None, None)
+
+
+# jax.monitoring event -> (span, stage). A jaxpr trace runs on every jit
+# cache miss of the Python path, also where the program is then found
+# built; lowering and the backend step (an XLA compile, or a load from
+# the persistent cache) run only for a program not built yet
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("jaxpr", None),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("compile", "lower"),
+    "/jax/core/compile/backend_compile_duration": ("compile", "backend")}
+_compiles = threading.local()      # per thread: open (event, annotation)
+
+
+def _on_compile_start(event: str, value, **kw) -> None:
+    span_stage = _COMPILE_STAGES.get(event)
+    if span_stage is None or not _recording():
+        return
+    name, stage = span_stage
+    args = {"fun": kw.get("fun_name", "")}
+    if stage is not None:
+        args["stage"] = stage
+    ann = _annotation(name, args)
+    ann.__enter__()
+    stack = getattr(_compiles, "stack", None)
+    if stack is None:
+        stack = _compiles.stack = []
+    stack.append((event, ann))
+
+
+def _on_compile_end(event: str, duration: float, **kw) -> None:
+    stack = getattr(_compiles, "stack", None)
+    if stack and stack[-1][0] == event:
+        stack.pop()[1].__exit__(None, None, None)
+
+
+gc.callbacks.append(_on_gc)
+jax.monitoring.register_scalar_listener(_on_compile_start)
+jax.monitoring.register_event_duration_secs_listener(_on_compile_end)

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.analysis import trace
 from repro.core.adapters import AdapterPack, apply_pack
+from repro.core.masks import path_str
 
 # A tenant names either the base model (None), one adapter ("a0"), or an
 # adapter *stack* (("a0", "lang_de")) — several adapters applied together,
@@ -105,21 +106,22 @@ class SwitchEngine:
     ``load``/``switch``/``load_fused`` accept either an ``AdapterPack`` or a
     registered adapter id, and the store handles disk residency.
 
-    ``blocking=False`` rides JAX async dispatch: ``load``/``unload`` return
-    as soon as the sparse scatter is *dispatched*, so the device-side
-    update overlaps whatever the host does next (e.g. an in-flight decode
-    step driven from another params tree). The swap is still ordered
-    before any later computation that reads ``self.params``; only the
-    host-side sync is skipped. ``SwitchStats.seconds`` then measures
-    dispatch, not completion — keep the default for switch-latency
-    benchmarking."""
+    Each ``load``, ``unload`` and ``switch`` dispatches its scatters
+    (spans ``switch.load``/``switch.unload``: the host's walk and
+    dispatch) and then, with ``blocking=True``, waits once for every leaf
+    it touched (span ``switch.wait``); ``SwitchStats.seconds`` runs from
+    the call until those leaves are ready. ``blocking=False`` skips the
+    wait and rides JAX async dispatch: the device-side update overlaps
+    whatever the host does next (e.g. an in-flight decode step driven
+    from another params tree), still ordered before any later computation
+    that reads ``self.params``. ``seconds`` then measures dispatch, not
+    completion — keep the default for switch-latency benchmarking."""
 
     def __init__(self, params, store=None, blocking: bool = True):
         self.params = params
         self.store = store
         self.blocking = blocking
         self.active: List[AdapterPack] = []
-        self.history: List[SwitchStats] = []
 
     def _resolve(self, pack) -> AdapterPack:
         if isinstance(pack, str):
@@ -129,45 +131,62 @@ class SwitchEngine:
             return self.store.get(pack)
         return pack
 
-    def _apply(self, pack: AdapterPack, sign: float):
-        self.params = apply_pack(self.params, pack, sign=sign)
+    def _apply(self, pack: AdapterPack, sign: float) -> None:
+        """Dispatch one pack's scatters (load or unload)."""
+        with trace.span("switch.load" if sign > 0 else "switch.unload",
+                        cat="switch", name=pack.name, bytes=pack.nbytes()):
+            self.params = apply_pack(self.params, pack, sign=sign)
+
+    def _wait(self, packs: List[AdapterPack]) -> None:
+        """Wait, once, until every leaf the packs touch is ready."""
+        if not self.blocking:
+            return
+        paths = {path for p in packs for path in p.entries}
+        leaves = [x for p, x in jax.tree_util.tree_flatten_with_path(
+            self.params)[0] if path_str(p) in paths]
+        with trace.span("switch.wait", cat="switch", leaves=len(leaves)):
+            jax.block_until_ready(leaves)
+
+    def _stats(self, name: str, t0: float,
+               packs: List[AdapterPack]) -> SwitchStats:
+        return SwitchStats(name, time.perf_counter() - t0,
+                           sum(p.num_params() for p in packs),
+                           sum(p.nbytes() for p in packs),
+                           _tree_bytes(self.params))
 
     def load(self, pack) -> SwitchStats:
         pack = self._resolve(pack)
         t0 = time.perf_counter()
-        with trace.span("switch.load", cat="switch", name=pack.name,
-                        bytes=pack.nbytes()):
-            self._apply(pack, +1.0)
-            if self.blocking:
-                jax.block_until_ready(jax.tree.leaves(self.params)[0])
-        dt = time.perf_counter() - t0
+        self._apply(pack, +1.0)
+        self._wait([pack])
         self.active.append(pack)
-        st = SwitchStats(pack.name, dt, pack.num_params(), pack.nbytes(),
-                         _tree_bytes(self.params))
-        self.history.append(st)
-        return st
+        return self._stats(pack.name, t0, [pack])
 
     def unload(self) -> Optional[SwitchStats]:
         if not self.active:
             return None
         pack = self.active.pop()
         t0 = time.perf_counter()
-        with trace.span("switch.unload", cat="switch", name=pack.name,
-                        bytes=pack.nbytes()):
-            self._apply(pack, -1.0)
-            if self.blocking:
-                jax.block_until_ready(jax.tree.leaves(self.params)[0])
-        dt = time.perf_counter() - t0
-        st = SwitchStats("-" + pack.name, dt, pack.num_params(),
-                         pack.nbytes(), _tree_bytes(self.params))
-        self.history.append(st)
-        return st
+        self._apply(pack, -1.0)
+        self._wait([pack])
+        return self._stats("-" + pack.name, t0, [pack])
 
     def switch(self, pack) -> SwitchStats:
-        """unload current -> load new; the paper's rapid-switch operation."""
-        while self.active:
-            self.unload()
-        return self.load(pack)
+        """unload current -> load new; the paper's rapid-switch operation.
+        One wait, at the end, on every leaf the unloads and the load
+        touched; the stats count the entries of every pack written."""
+        pack = self._resolve(pack)
+        t0 = time.perf_counter()
+        with trace.span("switch", cat="switch", name=pack.name):
+            written = []
+            while self.active:
+                written.append(self.active.pop())
+                self._apply(written[-1], -1.0)
+            self._apply(pack, +1.0)
+            self.active.append(pack)
+            written.append(pack)
+            self._wait(written)
+        return self._stats(pack.name, t0, written)
 
     def load_fused(self, packs: List,
                    weights: Optional[List[float]] = None) -> List[SwitchStats]:

@@ -161,6 +161,14 @@ def _slot_insert(big, small, slot: int, axes):
     return jax.tree.map(leaf, big, small, axes)
 
 
+def _first_token(logits) -> int:
+    """Greedy first token of a finished prompt (row 0), pulled to the host."""
+    row = logits[0]
+    with trace.span("sync"):
+        row = np.asarray(row)
+    return int(np.argmax(row))
+
+
 def _prefix_salt(adapter: Tenant) -> bytes:
     """Prefix-registry namespace for one request's adapter stack. KV pages
     hold the output of the forward pass that wrote them, so identical
@@ -365,8 +373,11 @@ class _EngineCommon:
         logits never come back to the host."""
         pslot = faults.poison_logits(self.step_count)
         if pslot is None and not self.nan_guard:
-            return np.asarray(jnp.argmax(logits, -1), np.int32), ()
-        lg = np.array(logits, np.float32)   # writable host copy
+            nxt = jnp.argmax(logits, -1)
+            with trace.span("sync"):
+                return np.asarray(nxt, np.int32), ()
+        with trace.span("sync"):
+            lg = np.array(logits, np.float32)   # writable host copy
         if pslot is not None and live:
             lg[live[pslot % len(live)]] = np.nan
         bad = tuple(s for s in live
@@ -732,8 +743,7 @@ class ServingEngine(_EngineCommon):
             self._active[slot] = p
             p.fut.submitted_step = self.step_count
             self._pos[slot] = p.prompt.shape[0] + prefix
-            first = int(np.argmax(np.asarray(logits[0])))
-            self._emit(slot, first)
+            self._emit(slot, _first_token(logits))
 
     def step(self) -> bool:
         """Admit queued requests into free slots, then run one decode step
@@ -1054,7 +1064,7 @@ class PagedServingEngine(_EngineCommon):
                 salt=_prefix_salt(r.fut.adapter))
             r.state = "live"
             self._pos[slot] = L_
-            self._emit(slot, int(np.argmax(np.asarray(logits[0]))))
+            self._emit(slot, _first_token(logits))
 
     # ------------------------------------------------------------------
     # Scheduling loop

@@ -394,5 +394,6 @@ def sidedelta_rows(x: jax.Array, rows: jax.Array, cols: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_budget + _VMEM_HEADROOM),
         interpret=interpret,
+        name="sidedelta",
     )(ids, scale, x, *(t.reshape(A, 1, K_pad) for t in (rows, cols, vals)))
     return out[:, :S, :m]

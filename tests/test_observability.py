@@ -73,16 +73,20 @@ def test_ring_buffer_drops_oldest():
 
 
 def test_jsonl_and_chrome_export(tmp_path):
+    """The JSONL export round-trips every event through the replay loader
+    (Perfetto reads the profiler's trace, which carries the same spans)."""
     tr = trace.install()
     with trace.span("work", cat="c", k=1):
         trace.instant("tick")
     jsonl = tr.to_jsonl(str(tmp_path / "t.jsonl"))
     loaded = replay.load_trace(jsonl)
     assert [e["name"] for e in loaded] == [e["name"] for e in tr.events()]
-    chrome = json.load(open(tr.to_chrome(str(tmp_path / "t.json"))))
-    phs = {e["name"]: e for e in chrome["traceEvents"]}
-    assert phs["work"]["ph"] == "X" and "dur" in phs["work"]
-    assert phs["tick"]["ph"] == "i" and phs["tick"]["s"] == "t"
+    phs = {e["name"]: e for e in loaded}
+    assert phs["work"]["ph"] == "X" and phs["work"]["dur"] >= 0
+    assert phs["work"]["cat"] == "c" and phs["work"]["args"] == {"k": 1}
+    assert phs["tick"]["ph"] == "i" and phs["tick"]["depth"] == 1
+    assert [json.loads(line)["name"] for line in open(jsonl)] == \
+        ["work", "tick"]
 
 
 def test_disabled_tracing_is_noop():
@@ -432,3 +436,110 @@ def test_loadgen_drives_paged_engine(paged_setup):
     assert rep.tokens_out > 0 and rep.steps > 0
     assert rep.goodput_tok_s <= rep.tokens_per_s + 1e-9
     assert rep.slo_violation_rate == 0.0       # SLO is 60s: all met
+
+
+# ---------------------------------------------------------------------------
+# The profiler as a second sink
+# ---------------------------------------------------------------------------
+
+def _host_events(trace_dir):
+    """name -> [(start_ns, end_ns, stats)] of the ``repro.*`` host events
+    in the newest profiler trace under ``trace_dir``."""
+    import glob
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("repro."):
+                        out.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns,
+                             dict(e.stats)))
+    return out
+
+
+def _inside(iv, outer):
+    return any(s <= iv[0] and iv[1] <= e for s, e, _ in outer)
+
+
+def test_profiler_session_records_program_spans(paged_setup, tmp_path):
+    """Under a profiler session the engine's and the switch's spans land
+    on the host plane as ``repro.*`` events, properly nested, with no
+    tracer installed (steps) and with one (the switch, which also fills
+    the ring buffer); so do a garbage collection and a compile."""
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    from repro import core
+    cfg, params, packs = paged_setup
+    engine = _paged_engine(cfg, params, packs)
+    prompt = np.arange(3, 9) % cfg.vocab_size
+    engine.submit(prompt, packs[0].name, max_tokens=2)
+    engine.run()                                   # compile off the record
+    sw = core.SwitchEngine(params)
+    sw.load(packs[0])
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.submit(prompt, packs[1].name, max_tokens=3)
+        for _ in range(4):          # two chunks, then two decode steps
+            engine.step()
+        tr = trace.install()
+        sw.switch(packs[1])
+        trace.uninstall()
+        gc.collect()
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path)
+    steps, decodes, syncs = ev["repro.step"], ev["repro.decode"], \
+        ev["repro.sync"]
+    assert len(steps) == 4 and len(decodes) == 2
+    assert all(_inside(d, steps) for d in decodes)
+    assert all(_inside(s, steps) for s in syncs)
+    assert all(any(d[0] <= s[0] and s[1] <= d[1] for s in syncs)
+               for d in decodes)
+    assert any(e[2].get("step") for e in steps)    # span.set() reaches it
+    (switch,) = ev["repro.switch"]
+    for name in ("repro.switch.unload", "repro.switch.load",
+                 "repro.switch.wait"):
+        assert len(ev[name]) == 1 and _inside(ev[name][0], [switch])
+    assert {e["name"] for e in tr.events()} == {
+        "switch", "switch.unload", "switch.load", "switch.wait"}
+    assert any(e[2].get("generation") == 2 for e in ev["repro.gc"])
+    assert {e[2].get("stage") for e in ev["repro.compile"]} == {
+        "lower", "backend"}
+    assert ev["repro.jaxpr"]
+
+
+def test_blocking_switch_waits_for_every_leaf(paged_setup):
+    import jax
+    from repro import core
+    _, params, packs = paged_setup
+    sw = core.SwitchEngine(params)
+    sw.load(packs[0])
+    st = sw.switch(packs[1])
+    assert all(x.is_ready() for x in jax.tree.leaves(sw.params))
+    assert [p.name for p in sw.active] == [packs[1].name]
+    assert st.entries_written == (packs[0].num_params()
+                                  + packs[1].num_params())
+    assert sw.unload().name == "-" + packs[1].name and not sw.active
+
+
+def test_span_opens_an_annotation_only_while_a_session_records(tmp_path):
+    import jax
+    assert trace.span("x") is trace.span("y")          # nothing records
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sp = trace.span("x", a=1)
+        assert sp is not trace.span("y")
+        with sp as s:
+            assert s.set(b=2) is s
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.span("x") is trace.span("y")
+    (x,) = _host_events(tmp_path)["repro.x"]
+    assert x[2] == {"a": 1, "b": 2}

@@ -11,6 +11,7 @@ process may load the TPU library, and the test workers all import this
 file.
 """
 import os
+import re
 from unittest import mock
 
 import pytest
@@ -103,7 +104,9 @@ def test_sidedelta_compiles_for_v5e(one_chip, n, m, S, x_dtype, table):
         return sidedelta_rows(x, rows, cols, vals, ids, m,
                               scale=scale if int8 else None)
 
-    _compile_for_tpu(fn, *args)
+    hlo = _compile_for_tpu(fn, *args).as_text()
+    # the benchmark's kernel time and roofline find the kernel by this name
+    assert re.search(r"%sidedelta(\.\d+)* = [^\n]*custom-call\(", hlo)
 
 
 @pytest.mark.parametrize("moments", [jnp.float32, jnp.int8])
