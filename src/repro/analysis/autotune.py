@@ -1,14 +1,15 @@
-"""Measured-time (bm, kc) tile autotuning for the sidedelta kernel.
+"""Measured-time sub-chunk (kc) autotuning for the sidedelta kernel.
 
-``kernels/sidedelta.py::plan_tiles`` picks its tile plan from a static
-VMEM byte budget — a safe bound, not a measurement. This module closes
-the loop: for each ``(S, n, m, K)`` shape class it sweeps the feasible
-``(bm, kc)`` candidates (lane-aligned, within the same budget), times
-the real ``sidedelta_rows`` dispatch, and persists the winners in a JSON
-plan cache that ``plan_tiles`` consults before falling back to the
-static heuristic (``sidedelta.install_plan_cache``; invalid entries are
-rejected at lookup, so a stale cache degrades to the heuristic instead
-of producing a broken kernel).
+``kernels/sidedelta.py::plan_tiles`` picks the sub-chunk length of the
+block-bucketed tables from a cost model over the block counts — a model,
+not a measurement. This module closes the loop: for each ``(n, m, K)``
+shape class it sweeps the feasible kc candidates (lane-aligned, within
+the same budget), lays random tables out under each, times the real
+``sidedelta_rows`` dispatch, and persists the winners in a JSON plan
+cache that ``plan_tiles`` consults before falling back to the heuristic
+(``sidedelta.install_plan_cache``; invalid entries are rejected at
+lookup, so a stale cache degrades to the heuristic instead of producing
+a broken kernel).
 
 Typical flow (also what ``python -m repro.analysis.autotune`` runs)::
 
@@ -23,7 +24,8 @@ Typical flow (also what ``python -m repro.analysis.autotune`` runs)::
 
 Shape classes are discovered, not guessed: ``observe()`` wraps a
 workload (an engine warmup, a bench) and records every distinct
-``plan_tiles`` request made under it. Measurements use the engine's own
+``plan_tiles`` request made under it (one per adapted weight and table
+build). Measurements use the engine's own
 dispatch path (``sidedelta_rows`` under ``jit``) so the numbers include
 exactly what serving pays — XLA-compiled off-TPU, Mosaic on TPU.
 """
@@ -32,7 +34,7 @@ from __future__ import annotations
 import contextlib
 import json
 import time
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
@@ -43,7 +45,7 @@ import importlib
 SD = importlib.import_module("repro.kernels.sidedelta")
 
 PlanKey = SD.PlanKey
-Plan = Tuple[int, int]
+Plan = int                                      # kc
 
 _LANE = SD._LANE
 
@@ -57,15 +59,15 @@ _observed: "dict[PlanKey, int]" = {}
 
 @contextlib.contextmanager
 def observe():
-    """Record every (S, n, m, K) shape class ``plan_tiles`` is asked to
+    """Record every (n, m, K) shape class ``plan_tiles`` is asked to
     plan while the context is open (run your serving warmup inside)."""
     orig = SD.plan_tiles
 
-    def recording(S, n, m, K, *, vmem_budget=SD.DEFAULT_VMEM_BUDGET,
-                  x_itemsize=4):
-        key = SD.plan_cache_key(S, n, m, K, vmem_budget, x_itemsize)
+    def recording(n, m, K, *, counts=None,
+                  vmem_budget=SD.DEFAULT_VMEM_BUDGET, x_itemsize=4):
+        key = SD.plan_cache_key(n, m, K, vmem_budget, x_itemsize)
         _observed[key] = _observed.get(key, 0) + 1
-        return orig(S, n, m, K, vmem_budget=vmem_budget,
+        return orig(n, m, K, counts=counts, vmem_budget=vmem_budget,
                     x_itemsize=x_itemsize)
 
     SD.plan_tiles = recording
@@ -89,49 +91,43 @@ def clear_observed() -> None:
 # ---------------------------------------------------------------------------
 
 def candidates(key: PlanKey, max_candidates: int = 12) -> List[Plan]:
-    """Feasible (bm, kc) plans for one shape class: lane-aligned tiles
-    within the class's VMEM budget, static plan included, deduped."""
-    S, n, m, K, budget, isize = key
-    m_pad = SD._round_up(max(m, 1), _LANE)
-    K_pad = SD._round_up(max(K, 1), _LANE)
-    bms = sorted({bm for bm in (_LANE, 2 * _LANE, 4 * _LANE, 8 * _LANE,
-                                16 * _LANE, m_pad)
-                  if _LANE <= bm <= m_pad})
-    kcs = sorted({min(kc, K_pad) for kc in (_LANE, 2 * _LANE, 4 * _LANE)})
-    out = [SD.plan_tiles(S, n, m, K, vmem_budget=budget, x_itemsize=isize)]
-    for bm in bms:
-        for kc in kcs:
-            plan = (bm, kc)
-            if plan in out:
-                continue
-            if SD.plan_is_valid(S, n, m, K, bm, kc, vmem_budget=budget,
-                                x_itemsize=isize):
-                out.append(plan)
+    """Feasible kc plans for one shape class: the sub-chunk lengths
+    ``plan_tiles`` weighs that fit the class's VMEM budget, the static
+    plan first."""
+    n, m, K, budget, isize = key
+    out = [SD.plan_tiles(n, m, K, vmem_budget=budget, x_itemsize=isize)]
+    for kc in SD._KCS:
+        if kc not in out and SD.plan_is_valid(n, m, K, kc,
+                                              vmem_budget=budget,
+                                              x_itemsize=isize):
+            out.append(kc)
     return out[:max_candidates]
 
 
 def measure_plan(key: PlanKey, plan: Plan, *, batch: int = 2,
                  adapters: int = 2, reps: int = 3, seed: int = 0,
-                 interpret: bool = False) -> float:
+                 S: int = 1, interpret: bool = False) -> float:
     """Best-of-``reps`` seconds for one jitted ``sidedelta_rows`` step at
-    this shape class under the given (bm, kc) override (one warmup rep
-    compiles)."""
+    this shape class over random tables laid out with sub-chunk ``plan``
+    (one warmup rep compiles)."""
     import jax
     import jax.numpy as jnp
 
-    S, n, m, K, budget, isize = key
-    bm, kc = plan
+    n, m, K, budget, isize = key
     rng = np.random.default_rng(seed)
     dt = jnp.float32 if isize == 4 else jnp.bfloat16
     x = jnp.asarray(rng.standard_normal((batch, S, n)), dt)
-    rows = jnp.asarray(rng.integers(0, n, (adapters, max(K, 1))), jnp.int32)
-    cols = jnp.asarray(rng.integers(0, m, (adapters, max(K, 1))), jnp.int32)
-    vals = jnp.asarray(rng.standard_normal((adapters, max(K, 1))),
-                       jnp.float32)
+    flat = [rng.choice(n * m, min(max(K, 1), n * m), replace=False)
+            for _ in range(adapters)]
+    t = SD.sidedelta_table(flat, [rng.standard_normal(len(f)).astype(
+        np.float32) for f in flat], n, m, kc=plan, vmem_budget=budget,
+        x_itemsize=isize)
+    rows, cols, vals, blocks = (jnp.asarray(a) for a in
+                                (t.rows, t.cols, t.vals, t.blocks))
     ids = jnp.asarray(rng.integers(0, adapters, (batch,)), jnp.int32)
 
     fn = jax.jit(lambda xx, ii: SD.sidedelta_rows(
-        xx, rows, cols, vals, ii, m, interpret=interpret, bm=bm, kc=kc,
+        xx, rows, cols, vals, ii, m, blocks=blocks, interpret=interpret,
         vmem_budget=budget))
     jax.block_until_ready(fn(x, ids))            # compile
     best = float("inf")
@@ -157,9 +153,9 @@ def autotune(shapes: Iterable[PlanKey], *, reps: int = 3, batch: int = 2,
             t = measure_plan(key, plan, reps=reps, batch=batch,
                              interpret=interpret)
             if verbose:
-                S, n, m, K = key[:4]
-                print(f"  (S={S},n={n},m={m},K={K}) bm={plan[0]:5d} "
-                      f"kc={plan[1]:4d}: {t * 1e6:9.1f} us")
+                n, m, K = key[:3]
+                print(f"  (n={n},m={m},K={K}) kc={plan:4d}: "
+                      f"{t * 1e6:9.1f} us")
             if t < best_t:
                 best_plan, best_t = plan, t
         if best_plan is not None:
@@ -173,11 +169,11 @@ def autotune(shapes: Iterable[PlanKey], *, reps: int = 3, batch: int = 2,
 
 def save_cache(plans: Dict[PlanKey, Plan], path: str,
                meta: Optional[dict] = None) -> str:
-    """JSON plan cache: ``{"S,n,m,K,budget,itemsize": [bm, kc], ...}``."""
-    body = {",".join(str(x) for x in key): [int(bm), int(kc)]
-            for key, (bm, kc) in sorted(plans.items())}
+    """JSON plan cache: ``{"n,m,K,budget,itemsize": kc, ...}``."""
+    body = {",".join(str(x) for x in key): int(kc)
+            for key, kc in sorted(plans.items())}
     with open(path, "w") as f:
-        json.dump({"schema": 1, "meta": dict(meta or {}), "plans": body},
+        json.dump({"schema": 2, "meta": dict(meta or {}), "plans": body},
                   f, indent=2, sort_keys=True)
         f.write("\n")
     return path
@@ -189,8 +185,8 @@ def load_cache(path: str) -> Dict[PlanKey, Plan]:
     plans: Dict[PlanKey, Plan] = {}
     for key, plan in doc.get("plans", {}).items():
         parts = tuple(int(x) for x in key.split(","))
-        if len(parts) == 6 and len(plan) == 2:
-            plans[parts] = (int(plan[0]), int(plan[1]))
+        if len(parts) == 5 and isinstance(plan, int):
+            plans[parts] = plan
     return plans
 
 
@@ -216,9 +212,7 @@ def _collect_smoke_shapes(arch: str, batch: int, prompt_len: int,
                           tokens: int, adapters: int) -> List[PlanKey]:
     """Run small multi-tenant and paged-engine workloads under
     ``observe()`` so the swept shape classes are exactly what the bench
-    tier plans for — both engines, at the benches' f32 compute precision
-    (the plan-cache key includes the input itemsize, so bf16-collected
-    classes would never hit under the f32 benches)."""
+    tier plans for: every adapted weight of both engines' table builds."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -243,8 +237,6 @@ def _collect_smoke_shapes(arch: str, batch: int, prompt_len: int,
         with observe():
             engine.generate({"tokens": toks}, names, tokens)
 
-        # the paged engine plans different classes: S = chunk_size prefill
-        # chunks and S = 1 decode over the live lane set
         pe = PagedServingEngine(cfg, params, slots=4, num_pages=64,
                                 page_size=2, max_len=prompt_len + tokens + 2,
                                 chunk_size=4)
@@ -260,17 +252,14 @@ def _collect_smoke_shapes(arch: str, batch: int, prompt_len: int,
 
 
 # Representative full-scale serving classes swept alongside whatever the
-# smoke warmup observes. The smoke classes pad to one lane tile, where the
-# static plan is trivially right; at these sizes the heuristic's
-# max-tiles-within-budget bet is measurably wrong on the XLA twin (1.5-2x,
-# this is where an autotuned cache earns its keep). (S, n, m, K).
-DEFAULT_EXTRA_SHAPES = ((16, 1024, 1024, 8000), (1, 2048, 2048, 16000))
+# smoke warmup observes. (n, m, K).
+DEFAULT_EXTRA_SHAPES = ((1024, 1024, 8000), (2048, 2048, 16000))
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     import argparse
     ap = argparse.ArgumentParser(
-        description="Autotune sidedelta (bm, kc) plans for the smoke "
+        description="Autotune sidedelta kc plans for the smoke "
         "serving shape classes and write the plan cache JSON.")
     ap.add_argument("--arch", default="starcoder2-7b")
     ap.add_argument("--batch", type=int, default=8)
@@ -279,7 +268,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--adapters", type=int, default=3)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--shape", action="append", default=[],
-                    metavar="S,n,m,K", help="extra shape class to sweep "
+                    metavar="n,m,K", help="extra shape class to sweep "
                     "(repeatable; replaces the built-in extras)")
     ap.add_argument("--out", default="benchmarks/plan_cache.json")
     args = ap.parse_args(argv)
@@ -288,14 +277,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                                    args.tokens, args.adapters)
     extras = ([tuple(int(x) for x in s.split(",")) for s in args.shape]
               if args.shape else DEFAULT_EXTRA_SHAPES)
-    for S, n, m, K in extras:
-        key = SD.plan_cache_key(S, n, m, K)
+    for n, m, K in extras:
+        key = SD.plan_cache_key(n, m, K)
         if key not in shapes:
             shapes.append(key)
     print(f"observed {len(shapes)} shape classes "
           f"(incl. {len(extras)} full-scale extras); sweeping...")
     plans = autotune(shapes, reps=args.reps, verbose=True)
-    static = {k: SD.plan_tiles(*k[:4], vmem_budget=k[4], x_itemsize=k[5])
+    static = {k: SD.plan_tiles(*k[:3], vmem_budget=k[3], x_itemsize=k[4])
               for k in plans}
     changed = sum(plans[k] != static[k] for k in plans)
     path = save_cache(plans, args.out,

@@ -16,7 +16,8 @@ import numpy as np
 from repro.kernels.flash_decode import flash_decode_blocks
 from repro.kernels.masked_update import masked_update_tiles
 from repro.kernels.scatter_apply import scatter_apply_tiles
-from repro.kernels.sidedelta import sidedelta_rows
+from repro.kernels.sidedelta import (sidedelta_rows,  # noqa: F401
+                                     sidedelta_table)
 from repro.kernels.sparse_adamw import sparse_adamw_blocks, sparse_adamw_rows
 
 
@@ -73,25 +74,6 @@ def scatter_apply(w, counts, rows, cols, vals, alpha, *, bn=256, bm=256,
 # sidedelta (multi-tenant per-request adapters)
 # ---------------------------------------------------------------------------
 
-def sidedelta_table(flat_idx: np.ndarray, vals: np.ndarray, m: int, pad_to: int
-                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Host pre-pass: one adapter's packed (flat_idx, vals) over an (n, m)
-    weight -> (rows, cols, vals) each (pad_to,), zero-padded. Padded entries
-    point at (0, 0) with val 0, which the kernel applies as a harmless +0.
-    Runs once per adapter at registration time, not per batch."""
-    flat_idx = np.asarray(flat_idx, np.int64).reshape(-1)
-    vals = np.asarray(vals, np.float32).reshape(-1)
-    k = flat_idx.shape[0]
-    assert k <= pad_to, (k, pad_to)
-    rows = np.zeros((pad_to,), np.int32)
-    cols = np.zeros((pad_to,), np.int32)
-    vbuf = np.zeros((pad_to,), np.float32)
-    rows[:k] = (flat_idx // m).astype(np.int32)
-    cols[:k] = (flat_idx % m).astype(np.int32)
-    vbuf[:k] = vals
-    return rows, cols, vbuf
-
-
 def quantize_table(vals: np.ndarray) -> Tuple[np.ndarray, float]:
     """Symmetric int8 quantization of one adapter's (K,) value table.
     Returns (q int8, scale) with q * scale ~= vals; scale 1.0 for an
@@ -103,16 +85,15 @@ def quantize_table(vals: np.ndarray) -> Tuple[np.ndarray, float]:
     return q, scale
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("m", "interpret", "bm", "kc"))
-def sidedelta(x, rows, cols, vals, ids, *, m, scale=None, interpret=False,
-              bm=None, kc=None):
+@functools.partial(jax.jit, static_argnames=("m", "interpret"))
+def sidedelta(x, rows, cols, vals, ids, *, m, blocks=None, scale=None,
+              interpret=False):
     """Batched per-request sparse delta: (B, S, m) f32 with
     delta[b] = x[b] @ dW_{ids[b]} (ids[b] < 0 -> zeros). ``vals`` may be
     int8 with per-adapter ``scale`` (dequantised inside the kernel);
-    ``bm``/``kc`` override the VMEM tile plan."""
-    return sidedelta_rows(x, rows, cols, vals, ids, m, scale=scale,
-                          interpret=interpret, bm=bm, kc=kc)
+    ``blocks`` comes with tables laid out by ``sidedelta_table``."""
+    return sidedelta_rows(x, rows, cols, vals, ids, m, blocks=blocks,
+                          scale=scale, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

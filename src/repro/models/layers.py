@@ -77,8 +77,8 @@ SIDEDELTA_KEY = "sd.base"
 # Which execution mode the sidedelta kernel uses, read at TRACE time (same
 # discipline as compute_precision): None = auto (Pallas interpret emulation
 # off-TPU, compiled Mosaic on TPU); True/False force it. interpret=False
-# off-TPU compiles the kernel's tile plan through XLA — what CPU CI uses to
-# guard the tiling/masking logic against TPU-only lowering bugs. "xla"
+# off-TPU compiles the XLA twin, which computes the same sums from the same
+# tables in any order. "xla"
 # forces the pure-jnp XLA twin on every backend; the twin is differentiable
 # w.r.t. the value tables, which the multi-adapter trainer's forward needs.
 SIDEDELTA_INTERPRET = None
@@ -106,14 +106,19 @@ def sidedelta_backend(interpret):
 
 def sidedelta_weight(base: jax.Array, rows: jax.Array, cols: jax.Array,
                      vals: jax.Array, ids: jax.Array,
-                     scale: Optional[jax.Array] = None) -> dict:
+                     scale: Optional[jax.Array] = None,
+                     blocks: Optional[jax.Array] = None) -> dict:
     """base: (n, m); rows/cols/vals: (A, K) packed per-adapter deltas
     (vals f32, or int8 with per-adapter ``scale`` (A,) f32);
-    ids: (B,) int32 per-request adapter slot (-1 = base only)."""
+    ids: (B,) int32 per-request adapter slot (-1 = base only);
+    blocks: (A, C) sub-chunk origins of block-bucketed tables (the Pallas
+    kernel needs them; the XLA twin reads tables in any order)."""
     w = {SIDEDELTA_KEY: base, "sd.rows": rows, "sd.cols": cols,
          "sd.vals": vals, "sd.ids": ids}
     if scale is not None:
         w["sd.scale"] = scale
+    if blocks is not None:
+        w["sd.blocks"] = blocks
     return w
 
 
@@ -158,7 +163,7 @@ def _pdot_sidedelta(x: jax.Array, w: dict) -> jax.Array:
     y = pdot(x, base)
     delta = sidedelta(x, w["sd.rows"], w["sd.cols"], w["sd.vals"],
                       w["sd.ids"], m=base.shape[-1],
-                      scale=w.get("sd.scale"),
+                      blocks=w.get("sd.blocks"), scale=w.get("sd.scale"),
                       interpret=sidedelta_interpret())
     return (y.astype(jnp.float32) + delta).astype(y.dtype)
 

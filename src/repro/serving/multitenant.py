@@ -11,10 +11,12 @@ batched sparse side term in the forward pass:
 
 The side term is computed by the Pallas ``sidedelta`` kernel
 (repro/kernels/sidedelta.py) from packed per-adapter (row, col, val)
-tables; the weight leaves of the served parameter tree are replaced by
-``layers.sidedelta_weight`` bundles, which ``layers.pdot`` understands and
-which survive the LM's ``lax.scan`` over stacked layer weights (every table
-carries the weight's leading layer dims).
+tables, bucketed by 128 x 128 weight block at table build so that each
+of the kernel's sub-chunks touches one block; the weight leaves of the
+served parameter tree are replaced by ``layers.sidedelta_weight`` bundles,
+which ``layers.pdot`` understands and which survive the LM's ``lax.scan``
+over stacked layer weights (every table carries the weight's leading layer
+dims).
 
 Fused-state scheduling: with a ``core.switching.FusedLRU`` scheduler, the
 engine additionally fuses the *hot* tenant into the shared base (a single
@@ -381,10 +383,12 @@ class MultiTenantEngine:
         self._structural = False
 
     def _build_tables(self, side, packs, qpacks):
-        """Pack side deltas into device tables. Pure w.r.t. engine state
-        (reads only the passed snapshots + immutable ``_shapes``), so the
-        sync rebuild and the async build produce identical tables from
-        identical inputs. Returns (slots, tables, meta)."""
+        """Pack side deltas into device tables, laid out block by block
+        (``ops.sidedelta_table``). Pure w.r.t. engine state (reads only
+        the passed snapshots + immutable ``_shapes``), so the sync rebuild
+        and the async build produce identical tables from identical
+        inputs. Returns (slots, tables, meta); ``meta["pad_share"]`` is
+        the layout's padded entries over the real nonzeros."""
         from repro.kernels.ops import quantize_table
         # injected device-OOM point: covers the sync rebuild AND the async
         # build worker (poll_async_build contains worker failures; the hub
@@ -398,21 +402,19 @@ class MultiTenantEngine:
         pad = self.slot_pad
         A = max(-(-max(len(side), 1) // pad) * pad, 1)
         int8 = self.table_dtype == "int8"
+        vdt = np.int8 if int8 else np.float32
+        nnz = slots_run = 0
         for path in paths:
             shape = self._shapes[path]
             *lead, n, m = shape
             nl = int(np.prod(lead)) if lead else 1
-            kmax = 1
-            for pk in side.values():
-                if path in pk.entries:
-                    kmax = max(kmax, pk.entries[path][0].shape[-1])
             # int8 tables also shrink the index halves when the dims fit
             # int16 (the kernel widens them to int32 inside VMEM)
             idx_dt = (np.int16 if int8 and n < 2 ** 15 and m < 2 ** 15
                       else np.int32)
-            rows = np.zeros((nl, A, kmax), idx_dt)
-            cols = np.zeros((nl, A, kmax), idx_dt)
-            vals = np.zeros((nl, A, kmax), np.int8 if int8 else np.float32)
+            # one table per (layer, slot), layer-major like the device arrays
+            flat = [np.zeros(0, np.int64)] * (nl * A)
+            vals = [np.zeros(0, vdt)] * (nl * A)
             scale = np.ones((nl, A), np.float32)
             for name, pk in side.items():
                 if path not in pk.entries:
@@ -422,33 +424,35 @@ class MultiTenantEngine:
                 if direct is not None:       # store int8 -> table int8, 1:1
                     idxf, vq, sc = direct
                     idxf = np.asarray(idxf).reshape(nl, -1)
-                    k = idxf.shape[-1]
-                    rows[:, s, :k] = (idxf // m).astype(idx_dt)
-                    cols[:, s, :k] = (idxf % m).astype(idx_dt)
-                    vals[:, s, :k] = np.asarray(vq).reshape(nl, -1)
+                    vq = np.asarray(vq).reshape(nl, -1)
+                    for i in range(nl):
+                        flat[i * A + s], vals[i * A + s] = idxf[i], vq[i]
                     scale[:, s] = sc
                     continue
                 idx, val = pk.entries[path]
                 idxf = np.asarray(idx).reshape(nl, -1)
                 valf = np.asarray(val, np.float32).reshape(nl, -1) * pk.alpha
                 for i in range(nl):
-                    r, c, v = sidedelta_table(idxf[i], valf[i], m, kmax)
-                    rows[i, s], cols[i, s] = r.astype(idx_dt), c.astype(idx_dt)
+                    flat[i * A + s] = idxf[i]
                     if int8:
-                        vals[i, s], scale[i, s] = quantize_table(v)
+                        vals[i * A + s], scale[i, s] = quantize_table(valf[i])
                     else:
-                        vals[i, s] = v
-            entry = {
-                "rows": jnp.asarray(rows.reshape(tuple(lead) + (A, kmax))),
-                "cols": jnp.asarray(cols.reshape(tuple(lead) + (A, kmax))),
-                "vals": jnp.asarray(vals.reshape(tuple(lead) + (A, kmax))),
-            }
+                        vals[i * A + s] = valf[i]
+            t = sidedelta_table(flat, vals, n, m, idx_dtype=idx_dt)
+            nnz, slots_run = nnz + t.nnz, slots_run + t.slots
+            lead = tuple(lead)
+            entry = {k: jnp.asarray(a.reshape(lead + (A, a.shape[-1])))
+                     for k, a in (("rows", t.rows), ("cols", t.cols),
+                                  ("vals", t.vals), ("blocks", t.blocks))}
             if int8:
-                entry["scale"] = jnp.asarray(scale.reshape(tuple(lead) + (A,)))
+                entry["scale"] = jnp.asarray(scale.reshape(lead + (A,)))
             tables[path] = entry
+        # padded table entries in the sub-chunks the kernel runs, over the
+        # real nonzeros: how well the block layout fits these masks
         meta = {"tenants": len(side), "paths": len(tables),
                 "bytes": sum(int(x.nbytes) for t in tables.values()
-                             for x in t.values())}
+                             for x in t.values()),
+                "pad_share": round((slots_run - nnz) / max(nnz, 1), 4)}
         return slots, tables, meta
 
     # ------------------------------------------------------------------
@@ -578,7 +582,7 @@ class MultiTenantEngine:
         serving keeps resident in HBM). int8 tables shrink ``vals`` 4x and,
         when the dims fit int16, ``rows``/``cols`` 2x."""
         self._ensure_tables()
-        out = {"rows": 0, "cols": 0, "vals": 0, "scale": 0}
+        out = {"rows": 0, "cols": 0, "vals": 0, "blocks": 0, "scale": 0}
         for t in self._tables.values():
             for k in out:
                 if k in t:
@@ -718,7 +722,7 @@ class MultiTenantEngine:
                 return sidedelta_weight(
                     tree, t["rows"], t["cols"], t["vals"],
                     jnp.broadcast_to(ids, lead + ids.shape),
-                    scale=t.get("scale"))
+                    scale=t.get("scale"), blocks=t["blocks"])
             return tree
 
         return walk(self.shared, ())

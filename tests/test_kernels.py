@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from sidedelta_tables import sidedelta as raw_sidedelta
 
 
 @pytest.mark.parametrize("shape,bn,bm", [
@@ -90,7 +91,7 @@ def test_sidedelta_parity(B, S, n, m, A, K, dtype):
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int32)
     vals = jnp.asarray(rng.randn(A, K), jnp.float32)
     ids = jnp.asarray(rng.randint(-1, A, (B,)), jnp.int32)
-    out = ops.sidedelta(x, rows, cols, vals, ids, m=m, interpret=True)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=True)
     want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
@@ -105,53 +106,53 @@ def test_sidedelta_base_requests_untouched():
     cols = jnp.asarray(rng.randint(0, 16, (2, 11)), jnp.int32)
     vals = jnp.asarray(rng.randn(2, 11), jnp.float32)
     ids = jnp.asarray([-1, 0, -1], jnp.int32)
-    out = np.asarray(ops.sidedelta(x, rows, cols, vals, ids, m=16,
+    out = np.asarray(raw_sidedelta(x, rows, cols, vals, ids, m=16,
                                    interpret=True))
     assert np.all(out[0] == 0) and np.all(out[2] == 0)
     assert np.any(out[1] != 0)
 
 
-@pytest.mark.parametrize("B,S,n,m,A,K,bm", [
-    (3, 8, 128, 512, 3, 65, 128),     # dense MLP-ish: 4 m-tiles
-    (2, 4, 96, 384, 2, 33, 128),      # MoE expert-ish: odd n, 3 m-tiles
-    (2, 2, 160, 576, 2, 47, 256),     # MLA-ish: non-pow2 dims, ragged tile
+@pytest.mark.parametrize("B,S,n,m,A,K,kc", [
+    (3, 8, 128, 512, 3, 65, 128),     # dense MLP-ish: 4 column blocks
+    (2, 4, 96, 384, 2, 33, 128),      # MoE expert-ish: odd n, 3 blocks
+    (2, 2, 160, 576, 2, 47, 256),     # MLA-ish: non-pow2 dims, ragged block
 ])
 @pytest.mark.parametrize("interpret", [True, False])
-def test_sidedelta_tiled_parity(B, S, n, m, A, K, bm, interpret):
-    """v2 tiling: multi-m-tile grids must match the oracle in BOTH the
-    Pallas interpreter and compiled mode (on CPU the latter dispatches the
-    same tile plan through XLA — the interpret=False smoke that guards the
-    tiling/masking bookkeeping)."""
+def test_sidedelta_tiled_parity(B, S, n, m, A, K, kc, interpret):
+    """Multi-block tables must match the oracle in BOTH the Pallas
+    interpreter and compiled mode (on CPU the latter dispatches the XLA
+    twin, which reads the same tables in any order)."""
     rng = np.random.RandomState(hash((B, S, n, m, A, K)) % 2**31)
     x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
     rows = jnp.asarray(rng.randint(0, n, (A, K)), jnp.int32)
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int32)
     vals = jnp.asarray(rng.randn(A, K), jnp.float32)
     ids = jnp.asarray(rng.randint(-1, A, (B,)), jnp.int32)
-    out = ops.sidedelta(x, rows, cols, vals, ids, m=m, interpret=interpret,
-                        bm=bm, kc=128)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=interpret,
+                        kc=kc)
     want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
                                atol=1e-5, rtol=1e-5)
 
 
 def test_sidedelta_compiled_big_dff():
-    """The acceptance shape: m=8192 with interpret=False on CPU. The VMEM
-    plan must actually m-tile (bm < m) and the compiled result must match
-    the oracle to fp32 accuracy."""
-    from repro.kernels.sidedelta import (DEFAULT_VMEM_BUDGET, plan_tiles,
-                                         vmem_estimate)
+    """The acceptance shape: m=8192 with interpret=False on CPU. The plan
+    must be one of the weighed sub-chunk lengths and fit the VMEM budget
+    at this S, and the compiled result must match the oracle to fp32
+    accuracy."""
+    from repro.kernels.sidedelta import (_KCS, DEFAULT_VMEM_BUDGET,
+                                         plan_tiles, vmem_estimate)
     B, S, n, m, A, K = 2, 8, 256, 8192, 2, 1024
-    bm, kc = plan_tiles(S, n, m, K)
-    assert bm < m and m % bm == 0, (bm, m)
-    assert vmem_estimate(S, n, m, K, bm, kc) <= DEFAULT_VMEM_BUDGET
+    kc = plan_tiles(n, m, K)
+    assert kc in _KCS, kc
+    assert vmem_estimate(S, n, m, kc) <= DEFAULT_VMEM_BUDGET
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
     rows = jnp.asarray(rng.randint(0, n, (A, K)), jnp.int32)
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int32)
     vals = jnp.asarray(rng.randn(A, K), jnp.float32)
     ids = jnp.asarray([1, -1], jnp.int32)
-    out = ops.sidedelta(x, rows, cols, vals, ids, m=m, interpret=False)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=False)
     want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
                                atol=1e-5, rtol=1e-5)
@@ -159,17 +160,17 @@ def test_sidedelta_compiled_big_dff():
 
 @pytest.mark.parametrize("interpret", [True, False])
 def test_sidedelta_tile_straddle(interpret):
-    """Nonzeros ON the m-tile boundaries (last col of tile j, first col of
-    tile j+1) and duplicate (row, col) pairs must land exactly once each —
-    the local-column one-hot masks, it must not double-count or drop."""
-    n, m, bm = 32, 256, 128
+    """Nonzeros ON the block boundaries (last col of block j, first col of
+    block j+1) and duplicate (row, col) pairs must land exactly once each —
+    the block's one-hot masks, it must not double-count or drop."""
+    n, m = 32, 256
     rows = jnp.asarray([[0, 1, 2, 2, 3]], jnp.int32)
     cols = jnp.asarray([[127, 128, 255, 255, 0]], jnp.int32)  # edges + dup
     vals = jnp.asarray([[1.0, 2.0, 3.0, 4.0, 5.0]], jnp.float32)
     x = jnp.asarray(np.random.RandomState(3).randn(1, 4, n), jnp.float32)
     ids = jnp.asarray([0], jnp.int32)
-    out = ops.sidedelta(x, rows, cols, vals, ids, m=m, interpret=interpret,
-                        bm=bm, kc=128)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=interpret,
+                        kc=128)
     want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
@@ -195,8 +196,8 @@ def test_sidedelta_int8_tables(interpret):
     scale = jnp.asarray(np.array([s for _, s in qs], np.float32))
     assert vq.dtype == jnp.int8
     ids = jnp.asarray([0, -1, 2], jnp.int32)
-    out = ops.sidedelta(x, rows, cols, vq, ids, m=m, scale=scale,
-                        interpret=interpret, bm=256, kc=128)
+    out = raw_sidedelta(x, rows, cols, vq, ids, m=m, scale=scale,
+                        interpret=interpret, kc=128)
     want_q = ref.sidedelta_int8_ref(x, rows, cols, vq, scale, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want_q),
                                atol=1e-5, rtol=1e-5)
@@ -207,31 +208,144 @@ def test_sidedelta_int8_tables(interpret):
     assert np.all(np.asarray(out)[1] == 0)             # ids = -1 stays zero
 
 
+def _mask_flat(kind, n, m, K, rng):
+    """Flat nonzero indices of one (n, m) adapter: ``rand`` spreads K
+    evenly; ``struct`` is a SHiRA-struct mask (whole rows and columns plus
+    the diagonal), whose blocks hold from 0 to 128 * 128 nonzeros."""
+    if kind == "rand":
+        return np.sort(rng.choice(n * m, K, replace=False))
+    from repro.configs.base import AdapterConfig
+    from repro.core.masks import _struct_indices
+    return _struct_indices(n, m, AdapterConfig(struct_rows=2, struct_cols=3))
+
+
+@pytest.mark.parametrize("mask,B,S,n,m,A,K,int8,budget", [
+    ("rand", 3, 5, 300, 700, 3, 2000, False, None),    # ragged n, m, K
+    ("rand", 4, 1, 256, 384, 2, 1500, True, None),     # int8, int16 indices
+    ("struct", 2, 3, 200, 520, 2, 0, False, None),     # skewed block counts
+    ("struct", 3, 2, 260, 140, 3, 0, True, None),      # struct, int8
+    ("rand", 2, 20, 256, 256, 2, 900, False, 64 << 10),  # 3 S tiles of 8
+])
+def test_sidedelta_bucketed_kernel_parity(mask, B, S, n, m, A, K, int8,
+                                          budget):
+    """The Pallas kernel (interpret mode) over block-bucketed tables
+    matches ``sidedelta_ref`` / ``sidedelta_int8_ref``: per adapter a mask
+    of its own, ``ids = -1`` rows left at zero, dims and K off the tile
+    grid, and (with a tight VMEM budget) more than one S tile."""
+    from repro.kernels.sidedelta import DEFAULT_VMEM_BUDGET, sidedelta_rows
+    rng = np.random.default_rng(hash((mask, B, S, n, m, A)) % 2**31)
+    flats = [_mask_flat(mask, n, m, K, rng) for _ in range(A)]
+    vf = [(0.05 * rng.standard_normal(len(f))).astype(np.float32)
+          for f in flats]
+    if int8:
+        q = [ops.quantize_table(v) for v in vf]
+        vq, scale = [a for a, _ in q], np.array([s for _, s in q], np.float32)
+    t = ops.sidedelta_table(flats, vq if int8 else vf, n, m,
+                            idx_dtype=np.int16 if int8 else np.int32)
+    assert t.rows.dtype == (np.int16 if int8 else np.int32)
+    x = jnp.asarray(rng.standard_normal((B, S, n)), jnp.float32)
+    ids = jnp.asarray(np.r_[-1, rng.integers(0, A, B - 1)], jnp.int32)
+    out = sidedelta_rows(
+        x, jnp.asarray(t.rows), jnp.asarray(t.cols), jnp.asarray(t.vals),
+        ids, m, blocks=jnp.asarray(t.blocks),
+        scale=jnp.asarray(scale) if int8 else None, interpret=True,
+        vmem_budget=budget or DEFAULT_VMEM_BUDGET)
+    kmax = max(len(f) for f in flats)
+    pad = lambda arrs, dt: np.stack([np.pad(a, (0, kmax - len(a))).astype(dt)
+                                     for a in arrs])
+    rows = jnp.asarray(pad([f // m for f in flats], np.int32))
+    cols = jnp.asarray(pad([f % m for f in flats], np.int32))
+    if int8:
+        want = ref.sidedelta_int8_ref(x, rows, cols,
+                                      jnp.asarray(pad(vq, np.int8)),
+                                      jnp.asarray(scale), ids, m)
+    else:
+        want = ref.sidedelta_ref(x, rows, cols,
+                                 jnp.asarray(pad(vf, np.float32)), ids, m)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    assert not np.asarray(out)[0].any()                  # ids = -1
+
+
+@pytest.mark.parametrize("mask,n,m,K,kc", [
+    ("rand", 300, 700, 3000, None),
+    ("rand", 520, 260, 700, 256),
+    ("struct", 384, 640, 0, None),
+    ("struct", 130, 1000, 0, 512),
+])
+def test_sidedelta_bucketing_properties(mask, n, m, K, kc):
+    """The layout itself: every nonzero appears exactly once, each
+    sub-chunk lies in the one block its origin names, padding holds val 0
+    (and -1 sub-chunks only padding), and ``pad_share`` counts the padded
+    entries of the sub-chunks that run over the real nonzeros."""
+    rng = np.random.default_rng(hash((mask, n, m, K)) % 2**31)
+    flats = [_mask_flat(mask, n, m, K, rng) for _ in range(3)]
+    flats[1] = flats[1][: len(flats[1]) // 3]            # a shorter table
+    vals = [rng.uniform(0.5, 1.5, len(f)).astype(np.float32) for f in flats]
+    t = ops.sidedelta_table(flats, vals, n, m, kc=kc)
+    C = t.blocks.shape[1]
+    kc = t.rows.shape[1] // C
+    assert kc * C == t.rows.shape[1] and kc % 128 == 0
+    used = 0
+    for i, (f, v) in enumerate(zip(flats, vals)):
+        real = t.vals[i] != 0
+        got = t.rows[i][real].astype(np.int64) * m + t.cols[i][real]
+        order = np.argsort(got)
+        np.testing.assert_array_equal(got[order], np.sort(f))
+        np.testing.assert_array_equal(t.vals[i][real][order],
+                                      v[np.argsort(f)])
+        origin = np.repeat(t.blocks[i], kc)
+        run = origin >= 0
+        n0, m0 = origin[run] >> 16, origin[run] & 0xFFFF
+        assert not (n0 % 128).any() and not (m0 % 128).any()
+        r, c = t.rows[i][run], t.cols[i][run]
+        assert ((r >= n0) & (r < n0 + 128) & (c >= m0) & (c < m0 + 128)).all()
+        assert not t.vals[i][~run].any()
+        # the sub-chunks that run come first, then only -1
+        k = int(run.sum()) // kc
+        assert (t.blocks[i, :k] >= 0).all() and (t.blocks[i, k:] == -1).all()
+        used += k * kc
+    nnz = sum(len(f) for f in flats)
+    assert (t.nnz, t.slots) == (nnz, used)
+    assert t.pad_share == pytest.approx((used - nnz) / nnz)
+
+
 def test_sidedelta_plan_tiles_budget():
-    """The VMEM helper must respect its budget knob: a tighter budget
-    yields a smaller m-tile, never a plan that misses the grid, and a
+    """The plan must respect its budget knob: a tighter budget never yields
+    a longer sub-chunk, the plan fits the budget at every S tile, and a
     budget no plan fits raises instead of returning an over-budget one."""
-    from repro.kernels.sidedelta import plan_tiles, vmem_estimate
-    S, n, m, K = 16, 512, 16384, 2048
-    big_bm, big_kc = plan_tiles(S, n, m, K, vmem_budget=8 << 20)
-    small_bm, small_kc = plan_tiles(S, n, m, K, vmem_budget=2 << 20)
-    assert small_bm <= big_bm
-    assert vmem_estimate(S, n, m, K, small_bm, small_kc,
-                         vmem_budget=2 << 20) <= 2 << 20
-    for bm in (big_bm, small_bm):
-        assert bm % 128 == 0 and (-(-m // 128) * 128) % bm == 0
+    from repro.kernels.sidedelta import _ANY_S, plan_tiles, vmem_estimate
+    n, m, K = 512, 16384, 2048
+    big = plan_tiles(n, m, K, vmem_budget=8 << 20)
+    small = plan_tiles(n, m, K, vmem_budget=2 << 20)
+    assert small <= big
+    assert vmem_estimate(_ANY_S, n, m, small, vmem_budget=2 << 20) <= 2 << 20
+    for kc in (big, small):
+        assert kc % 128 == 0
     with pytest.raises(ValueError, match="no tile plan fits"):
-        plan_tiles(S, n, m, K, vmem_budget=256 << 10)
+        plan_tiles(n, m, K, vmem_budget=256 << 10)
 
 
 def test_sidedelta_table_roundtrip():
-    """Host prep: packed flat indices -> padded (rows, cols, vals)."""
-    flat = np.asarray([5, 17, 33], np.int64)
-    vals = np.asarray([1.0, -2.0, 3.0], np.float32)
-    rows, cols, v = ops.sidedelta_table(flat, vals, m=16, pad_to=5)
-    np.testing.assert_array_equal(rows, [0, 1, 2, 0, 0])
-    np.testing.assert_array_equal(cols, [5, 1, 1, 0, 0])
-    np.testing.assert_array_equal(v, [1.0, -2.0, 3.0, 0.0, 0.0])
+    """Host prep: packed flat indices -> block-bucketed (rows, cols, vals,
+    blocks): each block's run is padded to a whole sub-chunk at the
+    block's origin, and the triples read back exactly."""
+    flat = np.asarray([5, 17, 33, 200 * 256 + 130], np.int64)
+    vals = np.asarray([1.0, -2.0, 3.0, 4.0], np.float32)
+    t = ops.sidedelta_table([flat], [vals], n=256, m=256, kc=128)
+    assert t.rows.shape == (1, 256) and t.blocks.shape == (1, 2)
+    # block (0, 0) first, then block (128, 128)
+    np.testing.assert_array_equal(t.blocks[0], [0, 128 << 16 | 128])
+    np.testing.assert_array_equal(t.rows[0, :4], [0, 0, 0, 0])
+    np.testing.assert_array_equal(t.cols[0, :4], [5, 17, 33, 0])
+    np.testing.assert_array_equal(t.vals[0, :4], [1.0, -2.0, 3.0, 0.0])
+    assert t.rows[0, 128] == 200 and t.cols[0, 128] == 130
+    assert t.vals[0, 128] == 4.0
+    # padding sits at the block's origin with value 0
+    assert set(t.rows[0, 129:]) == {128} and set(t.cols[0, 129:]) == {128}
+    assert not t.vals[0, 129:].any()
+    assert (t.nnz, t.slots) == (4, 256)
+    assert t.pad_share == pytest.approx(252 / 4)
 
 
 @pytest.mark.parametrize("shape", [(256, 256), (512, 1024)])

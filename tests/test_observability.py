@@ -9,8 +9,8 @@ The contracts pinned here are the subsystem's acceptance criteria:
   * a traced paged-engine run decomposes: >= 90% of run() wall time lands
     in spans and ``step_timeline`` reproduces the scheduling loop;
   * ``plan_tiles`` consults the autotune cache — a cache hit changes the
-    chosen (bm, kc) with kernel parity intact, an over-budget cached plan
-    is rejected back to the static heuristic;
+    chosen kc with kernel parity intact, an over-budget cached plan is
+    rejected back to the static heuristic;
   * ``plan_tiles`` never plans over the VMEM budget (property test);
   * the load generator is deterministic per seed and drives the paged
     engine to completion with wall-clock latency/TTFT stamps.
@@ -183,71 +183,77 @@ def test_join_costs_roofline_ratio():
 # ---------------------------------------------------------------------------
 
 def test_plan_cache_hit_changes_plan_with_parity():
-    """plan_tiles consults the cache; a hit changes the chosen (bm, kc)
-    and the kernel's output is bit-identical under either plan."""
+    """plan_tiles consults the cache; a hit changes the chosen kc and the
+    kernel's output over tables laid out under either plan agrees."""
     import jax
     import jax.numpy as jnp
+    from repro.kernels import ops
+    from sidedelta_tables import sidedelta as raw_sidedelta
     S, n, m, K = 4, 256, 256, 300
-    static = SD.plan_tiles(S, n, m, K)
-    alt = (128, 128)
-    assert alt != static
-    assert SD.plan_is_valid(S, n, m, K, *alt,
+    static = SD.plan_tiles(n, m, K)
+    alt = 256 if static == 128 else 128
+    assert SD.plan_is_valid(n, m, K, alt,
                             vmem_budget=SD.DEFAULT_VMEM_BUDGET, x_itemsize=4)
 
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((2, S, n)), jnp.float32)
-    rows = jnp.asarray(rng.integers(0, n, (2, K)), jnp.int32)
-    cols = jnp.asarray(rng.integers(0, m, (2, K)), jnp.int32)
-    vals = jnp.asarray(rng.standard_normal((2, K)), jnp.float32)
+    rows = np.asarray(rng.integers(0, n, (2, K)), np.int32)
+    cols = np.asarray(rng.integers(0, m, (2, K)), np.int32)
+    vals = np.asarray(rng.standard_normal((2, K)), np.float32)
     ids = jnp.asarray([0, 1], jnp.int32)
 
-    want = jax.device_get(SD.sidedelta_rows(x, rows, cols, vals, ids, m))
+    want = jax.device_get(raw_sidedelta(x, rows, cols, vals, ids, m=m,
+                                        interpret=True))
     assert SD.plan_cache_stats["misses"] >= 1
 
-    key = SD.plan_cache_key(S, n, m, K)
+    key = SD.plan_cache_key(n, m, K)
     SD.install_plan_cache({key: alt})
-    assert SD.plan_tiles(S, n, m, K) == alt        # the hit changes the plan
+    assert SD.plan_tiles(n, m, K) == alt           # the hit changes the plan
     assert SD.plan_cache_stats["hits"] >= 1
-    got = jax.device_get(SD.sidedelta_rows(x, rows, cols, vals, ids, m))
+    t = ops.sidedelta_table([r.astype(np.int64) * m + c
+                             for r, c in zip(rows, cols)], vals, n, m)
+    assert t.rows.shape[1] // t.blocks.shape[1] == alt
+    got = jax.device_get(raw_sidedelta(x, rows, cols, vals, ids, m=m,
+                                       interpret=True))
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
 
     SD.clear_plan_cache()
-    assert SD.plan_tiles(S, n, m, K) == static     # and clears back
+    assert SD.plan_tiles(n, m, K) == static        # and clears back
 
 
 def test_plan_cache_rejects_invalid_entries():
-    S, n, m, K = 4, 256, 256, 300
-    static = SD.plan_tiles(S, n, m, K)
-    key = SD.plan_cache_key(S, n, m, K)
+    n, m, K = 256, 256, 300
+    static = SD.plan_tiles(n, m, K)
+    key = SD.plan_cache_key(n, m, K)
     # over-budget and misaligned entries fall back to the heuristic
-    for bad in ((1 << 20, 1 << 20), (100, 128), (0, 0)):
+    for bad in (1 << 20, 100, 0):
         SD.clear_plan_cache()
         SD.install_plan_cache({key: bad})
-        assert SD.plan_tiles(S, n, m, K) == static
+        assert SD.plan_tiles(n, m, K) == static
         assert SD.plan_cache_stats["rejected"] == 1
         assert SD.plan_cache_stats["hits"] == 0
 
 
 def test_autotune_save_load_install_roundtrip(tmp_path):
-    key = SD.plan_cache_key(4, 256, 256, 300)
-    plans = {key: (128, 128)}
+    key = SD.plan_cache_key(256, 256, 300)
+    plans = {key: 128}
     path = autotune.save_cache(plans, str(tmp_path / "plans.json"),
                                meta={"host": "test"})
     loaded = autotune.load_cache(path)
-    assert loaded == {key: (128, 128)}
+    assert loaded == {key: 128}
     assert autotune.install(loaded) == 1
-    assert SD.plan_cache() == {key: (128, 128)}
+    assert SD.plan_cache() == {key: 128}
     assert autotune.maybe_install_file(str(tmp_path / "absent.json")) == 0
 
 
 def test_observe_records_shape_classes():
     autotune.clear_observed()
     with autotune.observe():
-        SD.plan_tiles(4, 256, 256, 300)
-        SD.plan_tiles(4, 256, 256, 300)
-        SD.plan_tiles(1, 64, 64, 80)
+        SD.plan_tiles(256, 256, 300)
+        SD.plan_tiles(256, 256, 300)
+        SD.plan_tiles(64, 64, 80)
     shapes = autotune.observed_shapes()
-    assert shapes[0] == SD.plan_cache_key(4, 256, 256, 300)  # most requested
+    assert shapes[0] == SD.plan_cache_key(256, 256, 300)  # most requested
     assert len(shapes) == 2
     autotune.clear_observed()
     assert autotune.observed_shapes() == []
@@ -261,28 +267,28 @@ def test_checked_in_plan_cache_changes_a_plan():
     plans = autotune.load_cache(path)
     assert plans, "plan cache is empty"
     changed = 0
-    for key, (bm, kc) in plans.items():
-        S, n, m, K, budget, isize = key
-        assert SD.plan_is_valid(S, n, m, K, bm, kc, vmem_budget=budget,
+    for key, kc in plans.items():
+        n, m, K, budget, isize = key
+        assert SD.plan_is_valid(n, m, K, kc, vmem_budget=budget,
                                 x_itemsize=isize), key
-        if (bm, kc) != SD.plan_tiles(S, n, m, K, vmem_budget=budget,
-                                     x_itemsize=isize):
+        if kc != SD.plan_tiles(n, m, K, vmem_budget=budget,
+                               x_itemsize=isize):
             changed += 1
     assert changed >= 1
 
 
-def _check_plan_within_budget(S, n, m, K, budget):
-    """A returned plan is lane-aligned and fits the budget; plan_tiles
-    raises exactly when not even the (128, 128) floor fits."""
+def _check_plan_within_budget(n, m, K, budget):
+    """A returned plan is lane-aligned and fits the budget at every S
+    tile; plan_tiles raises exactly when not even kc = 128 fits."""
     try:
-        bm, kc = SD.plan_tiles(S, n, m, K, vmem_budget=budget, x_itemsize=4)
+        kc = SD.plan_tiles(n, m, K, vmem_budget=budget, x_itemsize=4)
     except ValueError:
-        assert SD.vmem_estimate(S, n, m, K, SD._LANE, SD._LANE,
+        assert SD.vmem_estimate(SD._ANY_S, n, m, SD._LANE,
                                 vmem_budget=budget) > budget
         return
-    assert bm % SD._LANE == 0 and kc % SD._LANE == 0
-    assert bm >= SD._LANE and kc >= SD._LANE
-    assert SD.vmem_estimate(S, n, m, K, bm, kc, vmem_budget=budget) <= budget
+    assert kc % SD._LANE == 0 and kc >= SD._LANE
+    assert SD.vmem_estimate(SD._ANY_S, n, m, kc,
+                            vmem_budget=budget) <= budget
 
 
 def test_plan_tiles_budget_random_sweep():
@@ -290,12 +296,11 @@ def test_plan_tiles_budget_random_sweep():
     still runs where hypothesis is not installed."""
     rng = np.random.default_rng(0)
     for _ in range(200):
-        S = int(rng.integers(1, 65))
         n = int(rng.integers(1, 4097))
         m = int(rng.integers(1, 8193))
         K = int(rng.integers(1, 65537))
         budget = int(rng.integers(1 << 18, 1 << 24))
-        _check_plan_within_budget(S, n, m, K, budget)
+        _check_plan_within_budget(n, m, K, budget)
 
 
 def test_plan_tiles_respects_vmem_budget_property():
@@ -303,16 +308,16 @@ def test_plan_tiles_respects_vmem_budget_property():
     from hypothesis import given, settings, strategies as st
 
     @settings(max_examples=60, deadline=None)
-    @given(S=st.integers(1, 64), n=st.integers(1, 4096),
+    @given(n=st.integers(1, 4096),
            m=st.integers(1, 8192), K=st.integers(1, 65536),
            budget=st.integers(1 << 18, 1 << 24),
            cached=st.booleans())
-    def prop(S, n, m, K, budget, cached):
+    def prop(n, m, K, budget, cached):
         SD.clear_plan_cache()
         if cached:     # a hostile cache must never break the budget either
             SD.install_plan_cache(
-                {SD.plan_cache_key(S, n, m, K, budget, 4): (1 << 17, 512)})
-        _check_plan_within_budget(S, n, m, K, budget)
+                {SD.plan_cache_key(n, m, K, budget, 4): 1 << 17})
+        _check_plan_within_budget(n, m, K, budget)
 
     prop()
     SD.clear_plan_cache()

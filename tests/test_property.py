@@ -13,6 +13,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import masks as M
 from repro.kernels import ops, ref
+from sidedelta_tables import sidedelta as raw_sidedelta
 
 SETTINGS = dict(max_examples=20, deadline=None)
 
@@ -60,9 +61,9 @@ def test_scatter_set_then_add_inverse(n, m, seed, alpha):
 def test_sidedelta_tiled_matches_ref(B, S, n, m, K, seed, int8, interpret):
     """The tiled+vectorised sidedelta (interpret AND compiled dispatch)
     must match ``sidedelta_ref`` for arbitrary (B, S, n, m, K): K=0,
-    all-base batches (ids=-1), nonzeros straddling m-tile boundaries (bm
-    is forced to 128 so any m > 128 tiles), and int8 tables within dequant
-    tolerance."""
+    all-base batches (ids=-1), nonzeros straddling block boundaries (the
+    blocks are 128 wide, so any m > 128 has several), and int8 tables
+    within dequant tolerance."""
     rng = np.random.RandomState(seed)
     A = rng.randint(1, 4)
     x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
@@ -85,8 +86,8 @@ def test_sidedelta_tiled_matches_ref(B, S, n, m, K, seed, int8, interpret):
         vals, scale = jnp.asarray(vf), None
         want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
         tol = 1e-5
-    out = ops.sidedelta(x, rows, cols, vals, ids, m=m, scale=scale,
-                        interpret=interpret, bm=128, kc=128)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, scale=scale,
+                        interpret=interpret, kc=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
     if int8:   # and the dequant stays within the serving tolerance

@@ -3,8 +3,10 @@
 Nothing runs: the TPU compiler that ships with jaxlib compiles for a
 topology that is described, not attached, so what Mosaic would refuse on
 the chip (misaligned blocks, VMEM over-use) fails here at no chip time.
-The widths are starcoder2-7b's published ones (d_model 4608, d_ff 18432)
-with SHiRA packs at 2% density, as ``launch/serve.py`` makes them.
+The widths are starcoder2-7b's published ones (d_model 4608, d_ff 18432),
+plus deepseek-coder-33b's gated FFN (7168 x 19200), with SHiRA packs at 2%
+density, as ``launch/serve.py`` makes them. The side-delta tables have the
+shapes ``sidedelta_table`` gives a random mask of that density.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this
@@ -14,6 +16,7 @@ import os
 import re
 from unittest import mock
 
+import numpy as np
 import pytest
 
 import jax
@@ -22,10 +25,12 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.kernels.sidedelta import sidedelta_rows
+from repro.kernels.sidedelta import sidedelta_rows, sidedelta_table
 
 CFG = get_config("starcoder2-7b")
 D, F = CFG.d_model, CFG.d_ff                     # 4608, 18432
+DSC = get_config("deepseek-coder-33b")
+D33, F33 = DSC.d_model, DSC.d_ff                 # 7168, 19200
 DENSITY = 0.02
 ADAPTERS = 3                                     # table slots
 BATCH = 4
@@ -79,6 +84,14 @@ def _compile_for_tpu(fn, *args):
     return compiled
 
 
+def _table_shape(n, m):
+    """(K, C) of the bucketed table of one random 2% mask: K entries in C
+    sub-chunks."""
+    flat = np.random.default_rng(0).choice(n * m, _nnz(n, m), replace=False)
+    t = sidedelta_table([flat], [np.ones(flat.shape, np.float32)], n, m)
+    return t.rows.shape[1], t.blocks.shape[1]
+
+
 @pytest.mark.parametrize("n,m,S,x_dtype,table", [
     (D, D, 1, jnp.bfloat16, "f32"),              # wq decode
     (D, F, 1, jnp.bfloat16, "f32"),              # w_up decode
@@ -86,9 +99,11 @@ def _compile_for_tpu(fn, *args):
     (F, D, PREFILL, jnp.bfloat16, "f32"),        # w_down prefill
     (D, F, 1, jnp.bfloat16, "int8"),             # w_up decode, int8 tables
     (D, D, 1, jnp.float32, "f32"),               # f32 parity decode
+    (F, D, 1, jnp.bfloat16, "f32"),              # w_down decode
+    (D33, F33, 1, jnp.bfloat16, "f32"),          # deepseek-coder-33b w_up
 ])
 def test_sidedelta_compiles_for_v5e(one_chip, n, m, S, x_dtype, table):
-    K = _nnz(n, m)
+    K, C = _table_shape(n, m)
     int8 = table == "int8"
     idx = jnp.int16 if int8 else jnp.int32
 
@@ -98,10 +113,11 @@ def test_sidedelta_compiles_for_v5e(one_chip, n, m, S, x_dtype, table):
     args = [spec((BATCH, S, n), x_dtype), spec((ADAPTERS, K), idx),
             spec((ADAPTERS, K), idx),
             spec((ADAPTERS, K), jnp.int8 if int8 else jnp.float32),
-            spec((BATCH,), jnp.int32), spec((ADAPTERS,), jnp.float32)]
+            spec((BATCH,), jnp.int32), spec((ADAPTERS,), jnp.float32),
+            spec((ADAPTERS, C), jnp.int32)]
 
-    def fn(x, rows, cols, vals, ids, scale):
-        return sidedelta_rows(x, rows, cols, vals, ids, m,
+    def fn(x, rows, cols, vals, ids, scale, blocks):
+        return sidedelta_rows(x, rows, cols, vals, ids, m, blocks=blocks,
                               scale=scale if int8 else None)
 
     hlo = _compile_for_tpu(fn, *args).as_text()
