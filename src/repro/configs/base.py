@@ -43,8 +43,16 @@ class MoEConfig:
     d_ff: int = 0                  # per-expert hidden dim
     first_dense_layers: int = 0    # leading layers that use a dense FFN instead
     first_dense_d_ff: int = 0      # hidden dim of those dense layers
-    capacity_factor: float = 1.25  # train-time token capacity per expert
     router_jitter: float = 0.0
+    norm_topk_prob: bool = True    # renormalise the top-k router weights
+    # The routed experts this chip holds: ``held_experts`` of them from
+    # ``first_held`` on (0 = all). ``num_experts`` stays the router's width.
+    held_experts: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -56,6 +64,18 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rotary scaling, as DeepSeek-V2's ``rope_scaling`` states it."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -86,6 +106,7 @@ class ModelConfig:
     attn_type: str = "gqa"         # gqa | mla | none
     qkv_bias: bool = False
     rope_theta: float = 10_000.0
+    yarn: Optional[YaRNConfig] = None  # YaRN-scaled rotary (MLA archs)
     norm_eps: float = 1e-5
     act: str = "silu"              # silu (SwiGLU) | gelu (vanilla MLP)
     tie_embeddings: bool = False

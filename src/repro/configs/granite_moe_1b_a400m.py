@@ -8,12 +8,12 @@ CONFIG = ModelConfig(
     name="granite-moe-1b-a400m", family="moe",
     num_layers=24, d_model=1024, num_heads=16, num_kv_heads=8,
     d_ff=512, vocab_size=49155,
-    moe=MoEConfig(num_experts=32, top_k=8, d_ff=512, capacity_factor=1.25),
+    moe=MoEConfig(num_experts=32, top_k=8, d_ff=512),
     tie_embeddings=True,
 )
 
 SMOKE_CONFIG = CONFIG.replace(
     num_layers=2, d_model=64, num_heads=4, num_kv_heads=2, d_ff=32,
     vocab_size=128,
-    moe=MoEConfig(num_experts=4, top_k=2, d_ff=32, capacity_factor=1.25),
+    moe=MoEConfig(num_experts=4, top_k=2, d_ff=32),
 )

@@ -3,10 +3,9 @@
 The model code is mesh-agnostic; the launch layer installs NamedShardings
 here before tracing. ``shard_act`` constrains the residual stream at scan
 boundaries (keeps remat-saved activations model-sharded); ``shard_as`` is the
-generic hook used for the MoE dispatch buffer ("moe_buf": expert-sharded so
-expert compute is local and token exchange becomes all-to-alls) and the loss
-chunks ("loss_act": gather the bf16 hidden once instead of psumming f32
-logits — found via the dry-run, EXPERIMENTS §Perf).
+generic hook used for the loss chunks ("loss_act": gather the bf16 hidden
+once instead of psumming f32 logits — found via the dry-run, EXPERIMENTS
+§Perf). "moe_ep_mesh" carries the mesh for the MoE layer's shard_map.
 """
 from __future__ import annotations
 

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (apply_rope, compute_dtype, dense, glorot,
-                                 rms_norm)
+                                 rms_norm, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -34,10 +34,12 @@ class KVCache(NamedTuple):
 # Core score/softmax/AV with GQA grouping — one q-chunk against full K.
 # ---------------------------------------------------------------------------
 
-def _attend_block(q, k, v, q_pos, k_pos, causal, prefix_len, kv_len=None):
+def _attend_block(q, k, v, q_pos, k_pos, causal, prefix_len, kv_len=None,
+                  scale=None):
     """q: (B, qc, H, D); k,v: (B, Sk, KV, Dk|Dv); positions are (qc,) or
     (B, qc) for per-request decode offsets, and (Sk,). ``kv_len`` may be a
-    scalar or (B,) per-request filled-cache length.
+    scalar or (B,) per-request filled-cache length; ``scale`` multiplies the
+    scores (default 1/sqrt(D)).
 
     Returns (B, qc, H, Dv). GQA grouping happens here without repeating KV.
     """
@@ -45,7 +47,8 @@ def _attend_block(q, k, v, q_pos, k_pos, causal, prefix_len, kv_len=None):
     Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
     qg = q.reshape(B, qc, KV, G, D)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
     scores = jnp.einsum(
         "bqkgd,bskd->bkgqs", qg.astype(compute_dtype()), k.astype(compute_dtype()),
         preferred_element_type=jnp.float32) * scale
@@ -68,14 +71,15 @@ def _attend_block(q, k, v, q_pos, k_pos, causal, prefix_len, kv_len=None):
 
 
 def chunked_attention(q, k, v, *, causal=True, q_offset=0, prefix_len=0,
-                      q_chunk=512, kv_len=None):
+                      q_chunk=512, kv_len=None, scale=None):
     """Flash-style attention over q-chunks. q: (B, Sq, H, D)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     k_pos = jnp.arange(Sk)
     if Sq <= q_chunk:
         q_pos = q_offset + jnp.arange(Sq)
-        return _attend_block(q, k, v, q_pos, k_pos, causal, prefix_len, kv_len)
+        return _attend_block(q, k, v, q_pos, k_pos, causal, prefix_len, kv_len,
+                             scale)
     while Sq % q_chunk:  # shrink to the nearest divisor of Sq
         q_chunk -= 1
     n = Sq // q_chunk
@@ -85,7 +89,7 @@ def chunked_attention(q, k, v, *, causal=True, q_offset=0, prefix_len=0,
         qi, i = inp
         q_pos = q_offset + i * q_chunk + jnp.arange(q_chunk)
         return None, _attend_block(qi, k, v, q_pos, k_pos, causal, prefix_len,
-                                   kv_len)
+                                   kv_len, scale)
 
     # nested remat: recompute each chunk's scores/probs in backward instead
     # of stacking (n, B, H, qc, S) probs to HBM (flash-attention-style
@@ -308,8 +312,7 @@ def mla_decode_paged(params, cfg: ModelConfig, x, cache: KVCache,
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_eff = jnp.einsum("bqhn,rhn->bqhr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    scale = 1.0 / jnp.sqrt(jnp.asarray(m.qk_nope_head_dim + m.qk_rope_head_dim,
-                                       jnp.float32))
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bqhr,bsr->bhqs", q_eff, cc.astype(jnp.float32))
               + jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
                            cr.astype(jnp.float32))) * scale
@@ -342,13 +345,30 @@ def mla_prefill_chunk(params, cfg: ModelConfig, x, cache: KVCache,
     k, v = _mla_expand_kv(params, cfg, cc, cr)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     out = _attend_block(q, k, v, positions, jnp.arange(k.shape[1]),
-                        causal=True, prefix_len=0, kv_len=kv_len)
+                        causal=True, prefix_len=0, kv_len=kv_len,
+                        scale=mla_softmax_scale(cfg))
     return dense(out.reshape(B, C, -1), params["wo"]), KVCache(ck, cv)
 
 
 # ---------------------------------------------------------------------------
 # MLA — Multi-head Latent Attention (DeepSeek-V2)
 # ---------------------------------------------------------------------------
+
+def mla_rope(cfg: ModelConfig, x, positions):
+    """Rotary embedding of MLA's rope dims, YaRN-scaled where the config
+    names it (``cfg.yarn``); ``mla_softmax_scale`` is its other half."""
+    return apply_rope(x, positions, cfg.rope_theta, cfg.yarn)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale(all_dim)^2 under YaRN, as
+    DeepSeek-V2's attention sets ``softmax_scale``."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if cfg.yarn is not None and cfg.yarn.mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    return scale
+
 
 def init_mla(key, cfg: ModelConfig) -> dict:
     m = cfg.mla
@@ -382,8 +402,7 @@ def _mla_q(params, cfg, x, positions):
         q = dense(x, params["wq"])
     q = q.reshape(B, S, H, m.qk_nope_head_dim + m.qk_rope_head_dim)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    return q_nope, q_rope
+    return q_nope, mla_rope(cfg, q_rope, positions)
 
 
 def _mla_ckv(params, cfg, x, positions):
@@ -391,7 +410,7 @@ def _mla_ckv(params, cfg, x, positions):
     ckv_full = dense(x, params["w_dkv"])
     c_kv, k_rope = jnp.split(ckv_full, [m.kv_lora_rank], axis=-1)
     c_kv = rms_norm(c_kv, params["kv_norm"]["scale"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = mla_rope(cfg, k_rope[:, :, None, :], positions)[:, :, 0]
     return c_kv, k_rope
 
 
@@ -416,7 +435,8 @@ def mla_train(params, cfg: ModelConfig, x, *, q_chunk=512, prefix_len=0):
     k, v = _mla_expand_kv(params, cfg, c_kv, k_rope)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     out = chunked_attention(q, k, v, causal=cfg.causal, q_chunk=q_chunk,
-                            prefix_len=prefix_len)
+                            prefix_len=prefix_len,
+                            scale=mla_softmax_scale(cfg))
     return dense(out.reshape(B, S, -1), params["wo"])
 
 
@@ -429,7 +449,8 @@ def mla_prefill(params, cfg: ModelConfig, x, cache_size: int, *,
     c_kv, k_rope = _mla_ckv(params, cfg, x, positions)
     k, v = _mla_expand_kv(params, cfg, c_kv, k_rope)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    out = chunked_attention(q, k, v, causal=True, q_chunk=q_chunk)
+    out = chunked_attention(q, k, v, causal=True, q_chunk=q_chunk,
+                            scale=mla_softmax_scale(cfg))
     cc = jnp.zeros((B, cache_size, m.kv_lora_rank), compute_dtype())
     cr = jnp.zeros((B, cache_size, m.qk_rope_head_dim), compute_dtype())
     cache = KVCache(
@@ -464,8 +485,7 @@ def mla_decode(params, cfg: ModelConfig, x, cache: KVCache, pos) -> Tuple[jax.Ar
     w_uk = params["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
     q_eff = jnp.einsum("bqhn,rhn->bqhr", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
-    scale = 1.0 / jnp.sqrt(jnp.asarray(m.qk_nope_head_dim + m.qk_rope_head_dim,
-                                       jnp.float32))
+    scale = mla_softmax_scale(cfg)
     scores = (jnp.einsum("bqhr,bsr->bhqs", q_eff, cc.astype(jnp.float32))
               + jnp.einsum("bqhd,bsd->bhqs", q_rope.astype(jnp.float32),
                            cr.astype(jnp.float32))) * scale

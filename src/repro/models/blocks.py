@@ -8,6 +8,10 @@ layer parameters regardless of family.
 Cache conventions (per layer):
   gqa/mla block : attention.KVCache
   mamba block   : mamba2.MambaCache
+
+The MoE block's prefill, decode and chunk prefill return (h, cache,
+counts): counts (held,) int32 are the token-choices its expert layer
+routed to each held expert.
 """
 from __future__ import annotations
 
@@ -20,7 +24,7 @@ from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import mamba2
 from repro.models.layers import dense, init_mlp, init_rms_norm, mlp, rms_norm
-from repro.models.moe import init_moe, moe_ffn
+from repro.models.moe import init_moe, moe_ffn, moe_serve
 
 
 # ---------------------------------------------------------------------------
@@ -136,9 +140,8 @@ def moe_block_prefill(params, cfg: ModelConfig, h, cache_size, *, prefix_len=0):
                                     prefix_len=prefix_len)
     h = h + a
     x = rms_norm(h, params["mlp_norm"]["scale"], cfg.norm_eps)
-    y, _ = moe_ffn(params["moe"], cfg, x)
-    h = h + y
-    return h, cache
+    y, counts = moe_serve(params["moe"], cfg, x)
+    return h + y, cache, counts
 
 
 def moe_block_decode(params, cfg: ModelConfig, h, cache, pos,
@@ -157,9 +160,8 @@ def moe_block_decode(params, cfg: ModelConfig, h, cache, pos,
         a, cache = attn.gqa_decode(params["attn"], cfg, x, cache, pos)
     h = h + a
     x = rms_norm(h, params["mlp_norm"]["scale"], cfg.norm_eps)
-    y, _ = moe_ffn(params["moe"], cfg, x)
-    h = h + y
-    return h, cache
+    y, counts = moe_serve(params["moe"], cfg, x)
+    return h + y, cache, counts
 
 
 def moe_block_prefill_chunk(params, cfg: ModelConfig, h, cache,
@@ -173,9 +175,8 @@ def moe_block_prefill_chunk(params, cfg: ModelConfig, h, cache,
                                           block_tables, start, kv_len)
     h = h + a
     x = rms_norm(h, params["mlp_norm"]["scale"], cfg.norm_eps)
-    y, _ = moe_ffn(params["moe"], cfg, x)
-    h = h + y
-    return h, cache
+    y, counts = moe_serve(params["moe"], cfg, x)
+    return h + y, cache, counts
 
 
 # ---------------------------------------------------------------------------

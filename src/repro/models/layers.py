@@ -201,19 +201,47 @@ def normal_init(key, shape, std=0.02, dtype=jnp.float32):
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    """Inverse frequencies, shape (head_dim // 2,)."""
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """Inverse frequencies, shape (head_dim // 2,). With a ``YaRNConfig``,
+    YaRN's blend as DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding`` builds
+    it: frequencies that turn fewer than ``beta_slow`` times over the
+    original context are divided by ``factor``, those that turn more than
+    ``beta_fast`` times are kept, with a linear ramp between."""
     exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
-    return 1.0 / (theta ** exponent)
+    inv = 1.0 / (theta ** exponent)
+    if yarn is None:
+        return inv
+
+    def dim_of(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, D); positions: (S,) or (..., S). Split-half convention."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (..., S, H, D); positions: (S,) or (..., S). Split-half convention.
+    ``yarn`` (a ``YaRNConfig``) scales the frequencies and, where its two
+    mscales differ, cos and sin."""
     d = x.shape[-1]
-    inv = rope_freqs(d, theta)                       # (d/2,)
+    inv = rope_freqs(d, theta, yarn)                 # (d/2,)
     angles = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, d/2)
-    cos = jnp.cos(angles)[..., :, None, :]           # (..., S, 1, d/2)
-    sin = jnp.sin(angles)[..., :, None, :]
+    mult = 1.0 if yarn is None else (yarn_mscale(yarn.factor, yarn.mscale)
+                                     / yarn_mscale(yarn.factor,
+                                                   yarn.mscale_all_dim))
+    cos = jnp.cos(angles)[..., :, None, :] * mult    # (..., S, 1, d/2)
+    sin = jnp.sin(angles)[..., :, None, :] * mult
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

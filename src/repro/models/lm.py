@@ -15,6 +15,8 @@ Entry points:
   train_loss(params, cfg, batch)                  -> (loss, metrics)
   prefill(params, cfg, batch, cache_size)         -> (last_logits, caches)
   decode_step(params, cfg, tokens, caches, pos)   -> (logits, caches)
+  (both with ``expert_counts=True`` also return the moe layers' routed
+  token-choices per held expert, (moe layers, held) int32)
   init_cache(cfg, batch, cache_size)              -> caches (zeros)
 """
 from __future__ import annotations
@@ -243,6 +245,27 @@ def encode(params, cfg: ModelConfig, batch):
 # Prefill / decode
 # ---------------------------------------------------------------------------
 
+def _whole_experts(stage_params, kind):
+    """A serving scan reads a moe stage's expert stacks whole, so that the
+    grouped matmul reads each layer's blocks in place of a slice copied out
+    of the stack: (the params the scan slices, the layer indices to scan,
+    a function of a layer's slice and index giving that layer's params).
+    Other stages scan as they are (no indices)."""
+    if kind != "moe":
+        return stage_params, None, lambda lp, i: lp
+    n = jax.tree.leaves(stage_params)[0].shape[0]
+    stacks = {k: v for k, v in stage_params["moe"].items()
+              if k.startswith("experts_")}
+    rest = dict(stage_params, moe={k: v for k, v in
+                                   stage_params["moe"].items()
+                                   if k not in stacks})
+
+    def layer(lp, i):
+        return dict(lp, moe=dict(lp["moe"], **stacks, layer=i))
+
+    return rest, jnp.arange(n), layer
+
+
 def _stage_prefill(stage_params, kind, cfg, h, cache_size, prefix_len,
                    shared=None):
     if kind == "hybrid":
@@ -258,31 +281,52 @@ def _stage_prefill(stage_params, kind, cfg, h, cache_size, prefix_len,
             return hh, {"mamba": mcaches, "attn": acache}
 
         h, caches = jax.lax.scan(group_body, h, stage_params)
-        return h, caches
+        return h, caches, None
 
     prefill_fn = _block_fns(kind)[2]
+    scanned, index, layer = _whole_experts(stage_params, kind)
 
-    def body(hh, lp):
-        return prefill_fn(lp, cfg, shard_act(hh), cache_size,
-                          prefix_len=prefix_len)
+    def body(hh, xs):
+        lp, i = xs
+        out = prefill_fn(layer(lp, i), cfg, shard_act(hh), cache_size,
+                         prefix_len=prefix_len)
+        return out[0], out[1:]
 
-    h, caches = jax.lax.scan(body, h, stage_params)
-    return h, caches
+    h, ys = jax.lax.scan(body, h, (scanned, index))
+    return (h,) + _split_counts(ys)
 
 
-def prefill(params, cfg: ModelConfig, batch, cache_size: int):
+def _split_counts(ys):
+    """A stage scan's stacked outputs -> (caches, counts or None): moe
+    blocks return (cache, counts), the others (cache,)."""
+    return ys[0], (ys[1] if len(ys) > 1 else None)
+
+
+def _with_counts(out, counts, expert_counts: bool):
+    """Append the stages' routed counts, stacked (moe layers, held)."""
+    if not expert_counts:
+        return out
+    counts = [c for c in counts if c is not None]
+    if not counts:
+        raise ValueError("expert_counts: the model has no moe layers")
+    return out + (jnp.concatenate(counts),)
+
+
+def prefill(params, cfg: ModelConfig, batch, cache_size: int,
+            expert_counts: bool = False):
     h, prefix_len = embed_inputs(params, cfg, batch)
-    caches = []
+    caches, counts = [], []
     for sp, (kind, _) in zip(params["stages"], stage_plan(cfg)):
-        h, cache = _stage_prefill(sp, kind, cfg, h, cache_size, prefix_len,
-                                  shared=params.get("shared_attn"))
+        h, cache, c = _stage_prefill(sp, kind, cfg, h, cache_size, prefix_len,
+                                     shared=params.get("shared_attn"))
         caches.append(cache)
+        counts.append(c)
     h = rms_norm(h, params["final_norm"]["scale"], cfg.norm_eps)
     last = h[:, -1]
     tie = params["embed"]["emb"] if cfg.tie_embeddings else None
     logits = unembed(params.get("unembed"), last, tie_to=tie,
                      softcap=cfg.logit_softcap, logical_vocab=cfg.vocab_size)
-    return logits, caches
+    return _with_counts((logits, caches), counts, expert_counts)
 
 
 def _stage_decode(stage_params, kind, cfg, h, caches, pos, shared=None,
@@ -303,20 +347,23 @@ def _stage_decode(stage_params, kind, cfg, h, caches, pos, shared=None,
             return hh, {"mamba": mcaches, "attn": acache}
 
         h, new = jax.lax.scan(group_body, h, (stage_params, caches))
-        return h, new
+        return h, new, None
 
     decode_fn = _block_fns(kind)[3]
+    scanned, index, layer = _whole_experts(stage_params, kind)
 
     def body(hh, xs):
-        lp, c = xs
-        return decode_fn(lp, cfg, hh, c, pos, block_tables=block_tables)
+        lp, c, i = xs
+        out = decode_fn(layer(lp, i), cfg, hh, c, pos,
+                        block_tables=block_tables)
+        return out[0], out[1:]
 
-    h, new = jax.lax.scan(body, h, (stage_params, caches))
-    return h, new
+    h, ys = jax.lax.scan(body, h, (scanned, caches, index))
+    return (h,) + _split_counts(ys)
 
 
 def decode_step(params, cfg: ModelConfig, tokens, caches, pos,
-                block_tables=None):
+                block_tables=None, expert_counts: bool = False):
     """tokens: (B, 1) int32; pos: scalar cache index shared by the batch, or
     a (B,) int32 vector of per-request indices (continuous batching — every
     slot decodes at its own depth). With ``block_tables`` ((B, nblk) int32)
@@ -325,29 +372,33 @@ def decode_step(params, cfg: ModelConfig, tokens, caches, pos,
     if block_tables is not None and cfg.family == "hybrid":
         raise NotImplementedError("paged decode covers attention caches only")
     h = embed(params["embed"], tokens)
-    new_caches = []
+    new_caches, counts = [], []
     for sp, cache, (kind, _) in zip(params["stages"], caches, stage_plan(cfg)):
-        h, nc = _stage_decode(sp, kind, cfg, h, cache, pos,
-                              shared=params.get("shared_attn"),
-                              block_tables=block_tables)
+        h, nc, c = _stage_decode(sp, kind, cfg, h, cache, pos,
+                                 shared=params.get("shared_attn"),
+                                 block_tables=block_tables)
         new_caches.append(nc)
+        counts.append(c)
     h = rms_norm(h, params["final_norm"]["scale"], cfg.norm_eps)
     tie = params["embed"]["emb"] if cfg.tie_embeddings else None
     logits = unembed(params.get("unembed"), h[:, 0], tie_to=tie,
                      softcap=cfg.logit_softcap, logical_vocab=cfg.vocab_size)
-    return logits, new_caches
+    return _with_counts((logits, new_caches), counts, expert_counts)
 
 
 def _stage_prefill_chunk(stage_params, kind, cfg, h, caches, block_tables,
                          start, kv_len):
     fn = (B.moe_block_prefill_chunk if kind == "moe"
           else B.dense_block_prefill_chunk)
+    scanned, index, layer = _whole_experts(stage_params, kind)
 
     def body(hh, xs):
-        lp, c = xs
-        return fn(lp, cfg, shard_act(hh), c, block_tables, start, kv_len)
+        lp, c, i = xs
+        out = fn(layer(lp, i), cfg, shard_act(hh), c, block_tables, start,
+                 kv_len)
+        return out[0], out[1]          # moe counts are not kept here
 
-    h, new = jax.lax.scan(body, h, (stage_params, caches))
+    h, new = jax.lax.scan(body, h, (scanned, caches, index))
     return h, new
 
 

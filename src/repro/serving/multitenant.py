@@ -42,8 +42,10 @@ Request-level serving with continuous batching lives one layer up, in
 per-slot adapter ids and cache positions.
 
 Limitations: adapters on ``w_uk``/``w_uv`` (MLA absorbed-decode weights,
-consumed via reshape rather than a matmul) are rejected — exclude them from
-``AdapterConfig.target_modules`` when serving MLA archs multi-tenant.
+consumed via reshape rather than a matmul) and on routed experts
+(``experts_w_*``, consumed by the grouped expert matmul) are rejected —
+exclude them from ``AdapterConfig.target_modules`` when serving MLA or MoE
+archs multi-tenant, or serve such adapters by switching.
 """
 from __future__ import annotations
 
@@ -69,9 +71,19 @@ from repro.runtime import faults
 BASE = None            # the "no adapter" tenant in a names list
 _BASE_SLOT = "__base__"
 
-# MLA absorbed-decode weights are reshaped, not matmul'd — pdot never sees
-# them, so a side-delta bundle there would crash (or silently diverge).
-UNSUPPORTED_LEAVES = ("w_uk", "w_uv")
+# MLA absorbed-decode weights are reshaped, not matmul'd, and routed experts
+# run in the grouped expert matmul — pdot never sees them, so a side-delta
+# bundle there would crash or silently serve the base weights.
+UNSUPPORTED_LEAVES = {
+    "w_uk": "consumed outside pdot (MLA absorbed decode; ROADMAP R3)",
+    "w_uv": "consumed outside pdot (MLA absorbed decode; ROADMAP R3)",
+    "experts_w_up": "a routed expert, served by the grouped expert matmul, "
+                    "which has no side-delta path (ROADMAP R2)",
+    "experts_w_gate": "a routed expert, served by the grouped expert matmul, "
+                      "which has no side-delta path (ROADMAP R2)",
+    "experts_w_down": "a routed expert, served by the grouped expert matmul, "
+                      "which has no side-delta path (ROADMAP R2)",
+}
 
 
 def _leaf_shapes(params) -> Dict[str, Tuple[int, ...]]:
@@ -241,8 +253,8 @@ class MultiTenantEngine:
             if leaf in UNSUPPORTED_LEAVES:
                 raise ValueError(
                     f"adapter {pack.name!r} targets {path!r}: {leaf} is "
-                    "consumed outside pdot (MLA absorbed decode); exclude it "
-                    "from target_modules for multi-tenant serving")
+                    f"{UNSUPPORTED_LEAVES[leaf]}; exclude it from "
+                    "target_modules for multi-tenant serving")
             if path not in self._shapes:
                 raise KeyError(f"adapter {pack.name!r} targets unknown "
                                f"weight {path!r}")

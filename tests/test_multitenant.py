@@ -407,3 +407,18 @@ def test_unsupported_target_rejected():
         jnp.zeros((2, 4), jnp.int32), jnp.zeros((2, 4), jnp.float32))})
     with pytest.raises(KeyError):
         engine.register(unknown)
+
+
+def test_routed_expert_targets_rejected():
+    """A side-delta bundle on routed experts would never reach pdot (the
+    grouped expert matmul reads the base experts), so it is refused."""
+    cfg = get_smoke_config("deepseek-v2-lite-16b")
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    engine = MultiTenantEngine(cfg, params)
+    E = cfg.moe.num_experts
+    for leaf in ("experts_w_up", "experts_w_gate", "experts_w_down"):
+        bad = AdapterPack("bad", {f"stages/1/moe/{leaf}": (
+            jnp.zeros((2, E, 4), jnp.int32),
+            jnp.zeros((2, E, 4), jnp.float32))})
+        with pytest.raises(ValueError, match="R2"):
+            engine.register(bad)

@@ -31,6 +31,7 @@ CFG = get_config("starcoder2-7b")
 D, F = CFG.d_model, CFG.d_ff                     # 4608, 18432
 DSC = get_config("deepseek-coder-33b")
 D33, F33 = DSC.d_model, DSC.d_ff                 # 7168, 19200
+DSV2 = get_config("deepseek-v2-lite-16b")        # d 2048, experts 1408 wide
 DENSITY = 0.02
 ADAPTERS = 3                                     # table slots
 BATCH = 4
@@ -145,3 +146,59 @@ def test_sparse_adamw_rows_compiles_for_v5e(one_chip, moments):
                                         mu_scale=ms, nu_scale=us)
 
     _compile_for_tpu(fn, *args)
+
+
+def _held_experts(one_chip, layers=None):
+    import dataclasses
+    cfg = DSV2.replace(moe=dataclasses.replace(DSV2.moe, held_experts=8))
+    d, ff, E = cfg.d_model, cfg.moe.d_ff, 8
+    lead = () if layers is None else (layers,)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"w_router": spec((d, cfg.moe.num_experts), jnp.float32),
+              "experts_w_up": spec(lead + (E, d, ff), jnp.float32),
+              "experts_w_gate": spec(lead + (E, d, ff), jnp.float32),
+              "experts_w_down": spec(lead + (E, ff, d), jnp.float32)}
+    return cfg, params, spec
+
+
+@pytest.mark.parametrize("tokens", [64, 8 * 1024])     # decode, prefill
+def test_moe_gmm_compiles_for_v5e(one_chip, tokens):
+    """The held-expert layer at deepseek-v2-lite widths, 8 of 64 experts
+    held, as a serving scan reads it: the 6 moe layers' (L, E, K, N) stacks
+    whole, at a traced layer. Routing, tile layout and both grouped
+    matmuls, for a decode step of 64 rows and a prefill of 8 x 1024 tokens
+    (6 choices a token); no slice of the stacks is copied out."""
+    from repro.models import moe
+    cfg, params, spec = _held_experts(one_chip, layers=6)
+
+    def fn(p, x, layer):
+        top_p, top_i, _ = moe.route(p, cfg, x)
+        return moe.routed_local(dict(p, layer=layer), cfg, x, 0, top_p,
+                                top_i)
+
+    hlo = _compile_for_tpu(fn, params, spec((tokens, cfg.d_model),
+                                            jnp.bfloat16),
+                           spec((), jnp.int32)).as_text()
+    assert len(re.findall(r"%moe_gmm(\.\d+)* = [^\n]*custom-call\(",
+                          hlo)) == 2
+    assert not re.search(r"= f32\[8,(2048,1408|1408,2048)\][^\n]* "
+                         r"(fusion|dynamic-slice|copy)\(", hlo)
+
+
+def test_moe_gmm_gradient_compiles_for_v5e(one_chip):
+    """The training expert layer's backward (megablox gmm and tgmm over the
+    same tiles) at the same widths, for 2 x 1024 tokens."""
+    from repro.models import moe
+    cfg, params, spec = _held_experts(one_chip)
+
+    def fn(p, x):
+        def loss(p, x):
+            top_p, top_i, _ = moe.route(p, cfg, x)
+            y, _ = moe.routed_local(p, cfg, x, 0, top_p, top_i)
+            return jnp.sum(y)
+        return jax.grad(loss, argnums=(0, 1))(p, x)
+
+    _compile_for_tpu(fn, params, spec((2 * 1024, cfg.d_model), jnp.bfloat16))
