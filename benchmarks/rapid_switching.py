@@ -1,9 +1,12 @@
 """Paper Fig. 5 / App. B analog: SHiRA scatter-load vs LoRA fuse latency.
 
-For weight dims 1024..4096 (paper uses up to 8192; CPU wall-clock here),
-measures:
-  * SHiRA switch: scatter-add of 1% packed updates (jnp path + Pallas
-    scatter_apply in interpret mode for the kernel-shape check),
+For weight dims 1024..4096 (paper uses up to 8192), measures wall-clock on
+the default backend:
+  * SHiRA switch: scatter-add of 1% packed updates, through XLA's scatter
+    (``scatter_packed_add``) and through the switch's tile kernel
+    (``ops.scatter_apply``: bucketing by weight block plus the one-hot
+    tile pass; the Pallas interpreter off the TPU, so only a chip run
+    times the kernel),
   * LoRA fuse: W + A@B at rank 64 (the paper's LVM rank),
   * pack I/O: loading the adapter from a format-v2 file via the
     ``repro.hub`` store, f32 and int8 (the cold-start path: an evicted
@@ -27,6 +30,7 @@ import _emit
 from repro.core import masks as M
 from repro.core.adapters import AdapterPack
 from repro.hub import load_pack, save_pack
+from repro.kernels import ops
 
 RANK = 64
 SPARSITY = 0.99
@@ -48,7 +52,8 @@ def main() -> None:
                     "(or PATH) with the _emit schema")
     args = ap.parse_args()
     rows = []
-    print("dim,shira_scatter_ms,lora_fuse_ms,speedup,"
+    interpret = jax.default_backend() != "tpu"
+    print("dim,shira_scatter_ms,shira_kernel_ms,lora_fuse_ms,speedup,"
           "shira_bytes_mb,lora_bytes_mb,lora_gemm_gflop,"
           "pack_load_f32_ms,pack_load_int8_ms,int8_shrink")
     rng = np.random.RandomState(0)
@@ -64,9 +69,12 @@ def main() -> None:
 
         scatter = jax.jit(lambda w, i, v: M.scatter_packed_add(
             w[None], i[None], v[None])[0])
+        kernel = jax.jit(lambda w, i, v: ops.scatter_apply(
+            w, [(i, v)], [1.0], interpret=interpret))
         fuse = jax.jit(lambda w, a, b: w + a @ b)
 
         t_s = timed(scatter, w, idx, vals) * 1e3
+        t_k = timed(kernel, w, idx, vals, reps=1 if interpret else 5) * 1e3
         t_f = timed(fuse, w, a, b) * 1e3
 
         shira_mb = k * 8 / 1e6                      # idx + val
@@ -84,11 +92,12 @@ def main() -> None:
             t_io[mode] = (time.perf_counter() - t0) * 1e3
         q = load_pack(os.path.join(tmp, f"d{dim}_int8.shpk"),
                       dequantize=False)
-        print(f"{dim},{t_s:.2f},{t_f:.2f},{t_f / t_s:.2f},"
+        print(f"{dim},{t_s:.2f},{t_k:.2f},{t_f:.2f},{t_f / t_s:.2f},"
               f"{shira_mb:.2f},{lora_mb:.2f},{gemm_gflop:.2f},"
               f"{t_io['f32']:.2f},{t_io['int8']:.2f},"
               f"{pack.nbytes() / q.nbytes():.1f}x")
         rows.append({"dim": dim, "shira_scatter_ms": t_s,
+                     "shira_kernel_ms": t_k,
                      "lora_fuse_ms": t_f,
                      "pack_load_f32_ms": t_io["f32"],
                      "pack_load_int8_ms": t_io["int8"],

@@ -85,7 +85,7 @@ from typing import Any, Dict, List, Optional
 import jax
 
 __all__ = ["Tracer", "install", "uninstall", "active", "enabled",
-           "span", "instant", "counter"]
+           "recording", "span", "instant", "counter"]
 
 
 class _NullSpan:
@@ -288,6 +288,12 @@ def active() -> Optional[Tracer]:
 
 def enabled() -> bool:
     return _tracer is not None
+
+
+def recording() -> bool:
+    """True while a tracer is installed or a profiler session records:
+    spans then keep their args, so a caller may pay to fill them."""
+    return _tracer is not None or _recording()
 
 
 def span(name: str, /, cat: str = "serving", **args):

@@ -12,6 +12,7 @@ the switch-cost asymmetry measured in the paper's Fig. 5 alongside wall-clock.
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -21,8 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis import trace
-from repro.core.adapters import AdapterPack, apply_pack
+from repro.core import masks as M
+from repro.core.adapters import AdapterPack
 from repro.core.masks import path_str
+from repro.kernels.scatter_apply import Layout, bucket, rows_walked
+from repro.kernels.scatter_apply import scatter_apply as scatter_tiles
 
 # A tenant names either the base model (None), one adapter ("a0"), or an
 # adapter *stack* (("a0", "lang_de")) — several adapters applied together,
@@ -99,6 +103,62 @@ def _tree_bytes(tree) -> int:
     return int(sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree)))
 
 
+def _one_device(x) -> bool:
+    """A leaf the switch program takes: a float matrix (or a stack of
+    them) held whole by one device. Anything else (a sharded leaf, a host
+    array) keeps XLA's scatter."""
+    return (isinstance(x, jax.Array) and x.ndim >= 2
+            and jnp.issubdtype(x.dtype, jnp.floating)
+            and len(x.sharding.device_set) == 1)
+
+
+def _blocks(shape) -> int:
+    """128 x 128 weight blocks of a (..., n, m) leaf."""
+    *lead, n, m = shape
+    return int(np.prod(lead, dtype=np.int64)) * -(-n // 128) * -(-m // 128)
+
+
+@functools.partial(jax.jit, static_argnames=("shapes",))
+def _bucket_program(entries, *, shapes):
+    """Bucket each leaf's (idx, vals) by weight block (one sort a leaf)."""
+    return tuple(bucket(i, v, shape) for (i, v), shape in zip(entries,
+                                                               shapes))
+
+
+@jax.jit
+def _copy_program(leaves):
+    """Fresh buffers for leaves the caller owns (the first write of each)."""
+    return tuple(jnp.copy(x) for x in leaves)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("interpret",))
+def _apply_program(leaves, outgoing, incoming, scales, *, interpret: bool):
+    """One pass over every adapted leaf, in place (the leaves are donated):
+    w - a_out * S_out + a_in * S_in, with ``scales`` = (-a_out, a_in) and
+    each leaf's layouts in ``outgoing`` / ``incoming``. A load's outgoing
+    layouts (and an unload's incoming ones) are empty: the same program
+    serves all three. Returns the new leaves and the rows of entries the
+    kernel walks."""
+    new, walked = [], jnp.zeros((), jnp.int32)
+    for w, out, inc in zip(leaves, outgoing, incoming):
+        new.append(_leaf_program(w, out, inc, scales, interpret=interpret))
+        walked = walked + rows_walked(out) + rows_walked(inc)
+    return tuple(new), walked
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _leaf_program(w, out, inc, scales, *, interpret: bool):
+    # a nested jit: leaves of one shape share one trace and lowering
+    return scatter_tiles(w, [out, inc], scales, interpret=interpret)
+
+
+def _empty(lay: Layout) -> Layout:
+    """A layout with no entries, shaped as ``lay``: every block's range is
+    [0, 0), so the kernel walks none of its rows."""
+    return lay._replace(bounds=jnp.zeros(lay.bounds.shape, jnp.int32))
+
+
 class SwitchEngine:
     """Holds deployed params; one active adapter (or fused set) at a time.
 
@@ -106,22 +166,49 @@ class SwitchEngine:
     ``load``/``switch``/``load_fused`` accept either an ``AdapterPack`` or a
     registered adapter id, and the store handles disk residency.
 
-    Each ``load``, ``unload`` and ``switch`` dispatches its scatters
-    (spans ``switch.load``/``switch.unload``: the host's walk and
-    dispatch) and then, with ``blocking=True``, waits once for every leaf
-    it touched (span ``switch.wait``); ``SwitchStats.seconds`` runs from
-    the call until those leaves are ready. ``blocking=False`` skips the
-    wait and rides JAX async dispatch: the device-side update overlaps
-    whatever the host does next (e.g. an in-flight decode step driven
-    from another params tree), still ordered before any later computation
-    that reads ``self.params``. ``seconds`` then measures dispatch, not
-    completion — keep the default for switch-latency benchmarking."""
+    A switch is one jitted program per pack layout (the adapted paths,
+    leaf shapes and per-leaf K, the same for every pack of a deployment):
+    for every adapted leaf it subtracts the outgoing pack and adds the
+    incoming one in a single pass of the ``scatter_apply`` tile kernel.
+    The incoming pack is bucketed by weight block on the device first
+    (span ``switch.bucket``, one sort a leaf); the engine keeps that
+    layout beside ``active`` while the pack is the last one loaded, so its
+    unload needs no sort. ``load`` (no outgoing pack), ``unload`` (no
+    incoming one), ``switch`` and ``load_fused`` all run the same program.
+
+    In place: a leaf this engine wrote is donated to its next program and
+    updated without a copy. The caller's own arrays are never donated —
+    the first write of each leaf copies it — so the tree passed in stays
+    valid. **A tree read from ``engine.params`` is invalid after the next
+    load, unload or switch**: read ``engine.params`` again after each.
+    Leaves not held whole by one device (sharded, or host arrays) take
+    XLA's scatter (``M.scatter_packed_add``) instead; the tracer counts
+    both kinds per program (``switch.kernel_leaves``,
+    ``switch.fallback_leaves``).
+
+    Each ``load``, ``unload`` and ``switch`` dispatches its programs
+    (spans ``switch.load``/``switch.unload``: the host's dispatch; the
+    load's holds ``switch.bucket``) and then, with ``blocking=True``,
+    waits once for every leaf it touched (span ``switch.wait``, which
+    records the rows of 128 entries the kernel walked while a trace
+    records); ``SwitchStats.seconds`` runs from the call until those
+    leaves are ready. ``blocking=False`` skips the wait and rides JAX
+    async dispatch: the device-side update overlaps whatever the host
+    does next, still ordered before any later computation that reads
+    ``self.params``. ``seconds`` then measures dispatch, not completion —
+    keep the default for switch-latency benchmarking."""
 
     def __init__(self, params, store=None, blocking: bool = True):
         self.params = params
         self.store = store
         self.blocking = blocking
         self.active: List[AdapterPack] = []
+        # path -> the leaf this engine last wrote there (donated next time)
+        self._mine: Dict[str, jax.Array] = {}
+        # bucketed layout of active[-1]: (pack, {path: Layout})
+        self._kept: Optional[Tuple[AdapterPack, dict]] = None
+        self._walked: List[jax.Array] = []
+        self._leaving: Optional[AdapterPack] = None   # see ``switch``
 
     def _resolve(self, pack) -> AdapterPack:
         if isinstance(pack, str):
@@ -132,20 +219,102 @@ class SwitchEngine:
         return pack
 
     def _apply(self, pack: AdapterPack, sign: float) -> None:
-        """Dispatch one pack's scatters (load or unload)."""
-        with trace.span("switch.load" if sign > 0 else "switch.unload",
-                        cat="switch", name=pack.name, bytes=pack.nbytes()):
-            self.params = apply_pack(self.params, pack, sign=sign)
+        """Dispatch one pack's unload (sign < 0) or load (sign > 0). A load
+        from ``switch`` carries the unload of the pack it replaces
+        (``_leaving``) in the same program."""
+        if sign < 0:
+            self._program(pack, None)
+        else:
+            self._program(self._leaving, pack)
+
+    def _program(self, out: Optional[AdapterPack],
+                 inc: Optional[AdapterPack]) -> None:
+        """Dispatch one pass: unload ``out`` and load ``inc`` (either may
+        be None) into every leaf they touch."""
+        flat, treedef = jax.tree_util.tree_flatten_with_path(self.params)
+        leaves = [x for _, x in flat]
+        where = {path_str(p): i for i, (p, _) in enumerate(flat)}
+        paths = [p for p in dict.fromkeys(
+            [*(out.entries if out else ()), *(inc.entries if inc else ())])
+            if p in where]
+        kernel = [p for p in paths if _one_device(leaves[where[p]])]
+        fallback = [p for p in paths if p not in set(kernel)]
+        kept = (self._kept[1] if out is not None and self._kept is not None
+                and self._kept[0] is out else None)
+        signed = [(pk, sign * pk.alpha) for pk, sign in ((out, -1.0),
+                                                         (inc, 1.0))
+                  if pk is not None]
+        with trace.span("switch.load" if inc is not None else "switch.unload",
+                        cat="switch", name=(inc or out).name,
+                        bytes=sum(p.nbytes() for p in (out, inc) if p),
+                        leaves=len(kernel),
+                        blocks=sum(_blocks(leaves[where[p]].shape)
+                                   for p in kernel)):
+            # the packs' layouts: the kept one, or bucketed now
+            lays = {(id(out), p): kept[p] for p in kept or ()}
+            todo = [(pk, p) for pk, _ in signed for p in kernel
+                    if p in pk.entries and (id(pk), p) not in lays]
+            if todo:
+                with trace.span("switch.bucket", cat="switch",
+                                leaves=len(todo)):
+                    lays.update(zip(
+                        [(id(pk), p) for pk, p in todo],
+                        _bucket_program(
+                            tuple(pk.entries[p] for pk, p in todo),
+                            shapes=tuple(leaves[where[p]].shape
+                                         for _, p in todo))))
+            # the caller's leaves are copied once, never donated
+            theirs = [p for p in kernel
+                      if self._mine.get(p) is not leaves[where[p]]]
+            if theirs:
+                for p, x in zip(theirs, _copy_program(
+                        tuple(leaves[where[p]] for p in theirs))):
+                    leaves[where[p]] = x
+            def layout(pk, p, other):
+                if pk is not None and p in pk.entries:
+                    return lays[(id(pk), p)]
+                return _empty(lays[(id(other), p)])
+
+            new, walked = _apply_program(
+                tuple(leaves[where[p]] for p in kernel),
+                tuple(layout(out, p, inc) for p in kernel),
+                tuple(layout(inc, p, out) for p in kernel),
+                jnp.asarray([-out.alpha if out else 0.0,
+                             inc.alpha if inc else 0.0], jnp.float32),
+                interpret=jax.default_backend() != "tpu")
+            for p, x in zip(kernel, new):
+                leaves[where[p]] = self._mine[p] = x
+            for p in fallback:
+                x = leaves[where[p]]
+                for pk, a in signed:
+                    if p in pk.entries:
+                        idx, val = pk.entries[p]
+                        x = M.scatter_packed_add(x, idx, val,
+                                                 alpha=a).astype(x.dtype)
+                leaves[where[p]] = x
+            self.params = jax.tree_util.tree_unflatten(treedef, leaves)
+        trace.counter("switch.kernel_leaves", len(kernel), cat="switch")
+        trace.counter("switch.fallback_leaves", len(fallback), cat="switch")
+        self._walked.append(walked)
+        if inc is not None:
+            self._kept = (inc, {p: lays[(id(inc), p)] for p in kernel
+                                if p in inc.entries})
+        elif kept is not None:
+            self._kept = None
 
     def _wait(self, packs: List[AdapterPack]) -> None:
         """Wait, once, until every leaf the packs touch is ready."""
+        walked, self._walked = self._walked, []
         if not self.blocking:
             return
         paths = {path for p in packs for path in p.entries}
         leaves = [x for p, x in jax.tree_util.tree_flatten_with_path(
             self.params)[0] if path_str(p) in paths]
-        with trace.span("switch.wait", cat="switch", leaves=len(leaves)):
+        with trace.span("switch.wait", cat="switch",
+                        leaves=len(leaves)) as sp:
             jax.block_until_ready(leaves)
+            if trace.recording():
+                sp.set(chunks=int(sum(int(w) for w in walked)))
 
     def _stats(self, name: str, t0: float,
                packs: List[AdapterPack]) -> SwitchStats:
@@ -153,6 +322,15 @@ class SwitchEngine:
                            sum(p.num_params() for p in packs),
                            sum(p.nbytes() for p in packs),
                            _tree_bytes(self.params))
+
+    def _drop_to(self, keep: int) -> List[AdapterPack]:
+        """Unload active packs until ``keep`` are left; the last of them
+        through its kept layout. Returns the packs unloaded."""
+        gone = []
+        while len(self.active) > keep:
+            gone.append(self.active.pop())
+            self._apply(gone[-1], -1.0)
+        return gone
 
     def load(self, pack) -> SwitchStats:
         pack = self._resolve(pack)
@@ -165,26 +343,28 @@ class SwitchEngine:
     def unload(self) -> Optional[SwitchStats]:
         if not self.active:
             return None
-        pack = self.active.pop()
         t0 = time.perf_counter()
-        self._apply(pack, -1.0)
+        pack = self._drop_to(len(self.active) - 1)[0]
         self._wait([pack])
         return self._stats("-" + pack.name, t0, [pack])
 
     def switch(self, pack) -> SwitchStats:
         """unload current -> load new; the paper's rapid-switch operation.
-        One wait, at the end, on every leaf the unloads and the load
-        touched; the stats count the entries of every pack written."""
+        The last active pack's unload and the new pack's load are one
+        program (others active beneath it are unloaded first). One wait,
+        at the end, on every leaf touched; the stats count the entries of
+        every pack written."""
         pack = self._resolve(pack)
         t0 = time.perf_counter()
         with trace.span("switch", cat="switch", name=pack.name):
-            written = []
-            while self.active:
-                written.append(self.active.pop())
-                self._apply(written[-1], -1.0)
-            self._apply(pack, +1.0)
+            written = self._drop_to(1)
+            self._leaving = self.active.pop() if self.active else None
+            try:
+                self._apply(pack, +1.0)
+            finally:
+                out, self._leaving = self._leaving, None
             self.active.append(pack)
-            written.append(pack)
+            written += ([out] if out is not None else []) + [pack]
             self._wait(written)
         return self._stats(pack.name, t0, written)
 

@@ -7,6 +7,6 @@
 # Validated against ref.py oracles in interpret mode (CPU container); the
 # BlockSpecs target TPU VMEM tiling.
 from repro.kernels import ops, ref  # noqa: F401
-from repro.kernels.ops import (bucket_updates, flash_decode,  # noqa: F401
-                               masked_update, scatter_apply, sidedelta,
-                               sidedelta_table, sparse_adamw)
+from repro.kernels.ops import (flash_decode, masked_update,  # noqa: F401
+                               scatter_apply, sidedelta, sidedelta_table,
+                               sparse_adamw)

@@ -1,9 +1,4 @@
-"""jit'd wrappers: host-side prep + pallas_call dispatch for every kernel.
-
-``bucket_updates`` is the host pre-pass for scatter_apply: it converts a
-packed (flat_idx, values) adapter into per-VMEM-tile buckets. It runs once
-per adapter at registration time (numpy), not per switch.
-"""
+"""jit'd wrappers: host-side prep + pallas_call dispatch for every kernel."""
 from __future__ import annotations
 
 import functools
@@ -15,7 +10,8 @@ import numpy as np
 
 from repro.kernels.flash_decode import flash_decode_blocks
 from repro.kernels.masked_update import masked_update_tiles
-from repro.kernels.scatter_apply import scatter_apply_tiles
+from repro.kernels.scatter_apply import bucket
+from repro.kernels.scatter_apply import scatter_apply as scatter_apply_tiles
 from repro.kernels.sidedelta import (sidedelta_rows,  # noqa: F401
                                      sidedelta_table)
 from repro.kernels.sparse_adamw import sparse_adamw_blocks, sparse_adamw_rows
@@ -25,49 +21,15 @@ from repro.kernels.sparse_adamw import sparse_adamw_blocks, sparse_adamw_rows
 # scatter_apply
 # ---------------------------------------------------------------------------
 
-def bucket_updates(flat_idx: np.ndarray, vals: np.ndarray, n: int, m: int,
-                   bn: int = 256, bm: int = 256
-                   ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Bucket packed updates by (bn, bm) tile.
-
-    Returns (counts (nt_i, nt_j), rows, cols, vals) with rows/cols tile-local
-    and padded to the max bucket size (zero-padded entries are masked out by
-    the per-tile count in the kernel)."""
-    flat_idx = np.asarray(flat_idx, np.int64)
-    vals = np.asarray(vals, np.float32)
-    r = flat_idx // m
-    c = flat_idx % m
-    ti = r // bn
-    tj = c // bm
-    nt_i, nt_j = n // bn, m // bm
-    tile_id = ti * nt_j + tj
-    order = np.argsort(tile_id, kind="stable")
-    tile_id_s = tile_id[order]
-    counts = np.bincount(tile_id_s, minlength=nt_i * nt_j)
-    u = max(int(counts.max()), 1)
-    rows = np.zeros((nt_i * nt_j, u), np.int32)
-    cols = np.zeros((nt_i * nt_j, u), np.int32)
-    vbuf = np.zeros((nt_i * nt_j, u), np.float32)
-    starts = np.concatenate([[0], np.cumsum(counts)])
-    r_s, c_s, v_s = r[order], c[order], vals[order]
-    for t in range(nt_i * nt_j):
-        s, e = starts[t], starts[t + 1]
-        k = e - s
-        if k:
-            rows[t, :k] = (r_s[s:e] % bn).astype(np.int32)
-            cols[t, :k] = (c_s[s:e] % bm).astype(np.int32)
-            vbuf[t, :k] = v_s[s:e]
-    return (counts.reshape(nt_i, nt_j).astype(np.int32),
-            rows.reshape(nt_i, nt_j, u), cols.reshape(nt_i, nt_j, u),
-            vbuf.reshape(nt_i, nt_j, u))
-
-
-@functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
-def scatter_apply(w, counts, rows, cols, vals, alpha, *, bn=256, bm=256,
-                  interpret=False):
-    alpha = jnp.asarray(alpha, jnp.float32).reshape(1)
-    return scatter_apply_tiles(w, counts, rows, cols, vals, alpha,
-                               bn=bn, bm=bm, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def scatter_apply(w, updates, scales, *, interpret=False):
+    """w (..., n, m) + sum_s scales[s] * scatter(values_s at flat indices_s):
+    ``updates`` is a sequence of (idx, vals) pairs of shape (..., K_s),
+    bucketed by weight block on the device and added by the one-hot tile
+    kernel in one pass over w."""
+    layouts = [bucket(i, v, w.shape) for i, v in updates]
+    return scatter_apply_tiles(w, layouts, jnp.asarray(scales, jnp.float32),
+                               interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -6,15 +6,6 @@ import jax
 import jax.numpy as jnp
 
 
-def scatter_apply_ref(w: jax.Array, flat_idx: jax.Array, vals: jax.Array,
-                      alpha: float = 1.0) -> jax.Array:
-    """w: (n, m); flat_idx/vals: (K,). W + alpha * scatter(vals)."""
-    n, m = w.shape
-    out = w.reshape(-1).astype(jnp.float32).at[flat_idx].add(
-        alpha * vals.astype(jnp.float32))
-    return out.reshape(n, m).astype(w.dtype)
-
-
 def sidedelta_ref(x: jax.Array, rows: jax.Array, cols: jax.Array,
                   vals: jax.Array, ids: jax.Array, m: int) -> jax.Array:
     """x: (B, S, n); rows/cols/vals: (A, K); ids: (B,) with -1 = no adapter.

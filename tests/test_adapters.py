@@ -143,6 +143,84 @@ def test_switch_engine_roundtrip_changed_fraction(setup):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
+def _random_packs(params, n=2, seed=30, std=0.05):
+    acfg = AdapterConfig(kind="shira", mask="rand", sparsity=0.97)
+    packs = []
+    for i in range(n):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+        v, aux = core.init_adapter(key, params, acfg)
+        v = jax.tree.map(lambda x: std * jax.random.normal(key, x.shape), v)
+        packs.append(core.pack_from_shira(f"r{i}", v, aux))
+    return packs
+
+
+def test_switch_engine_donates_only_its_own_leaves(setup):
+    """The caller's tree survives switching untouched; a leaf the engine
+    wrote is donated to (updated in place by) its next switch; and
+    A -> B -> A -> unload restores the base to f32 tolerance."""
+    cfg, params, _ = setup
+    base = [np.asarray(x) for x in jax.tree.leaves(params)]
+    a, b = _random_packs(params)
+    eng = core.SwitchEngine(params)
+    eng.switch(a)
+    first = eng.params
+    eng.switch(b)
+    for x, want in zip(jax.tree.leaves(params), base):
+        assert not x.is_deleted()
+        np.testing.assert_array_equal(np.asarray(x), want)
+    for p, x in jax.tree_util.tree_flatten_with_path(first)[0]:
+        assert x.is_deleted() == (M.path_str(p) in a.entries)
+    eng.switch(a)
+    eng.unload()
+    assert not eng.active
+    for x, want in zip(jax.tree.leaves(eng.params), base):
+        np.testing.assert_allclose(np.asarray(x), want, atol=1e-6)
+
+
+def test_switch_engine_entry_points_equal_apply_pack(setup):
+    """``load_fused`` and a non-blocking switch give what the plain
+    reference (``apply_pack``, XLA's scatter) gives."""
+    cfg, params, _ = setup
+    a, b = _random_packs(params, seed=31)
+    fused = core.SwitchEngine(params)
+    fused.load_fused([a, b], weights=[0.5, -2.0])
+    want = core.apply_pack(core.apply_pack(params, a, alpha=0.5),
+                           b, alpha=-2.0)
+    for x, y in zip(jax.tree.leaves(fused.params), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
+    eager = core.SwitchEngine(params, blocking=False)
+    eager.switch(a)
+    eager.switch(b)
+    want = core.apply_pack(params, b)
+    for x, y in zip(jax.tree.leaves(eager.params), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
+
+
+def test_switch_engine_counts_kernel_and_fallback_leaves(setup):
+    """Leaves held whole by one device take the tile kernel; others (here
+    a host array) keep XLA's scatter. The tracer counts both."""
+    from repro.analysis import trace
+    cfg, params, _ = setup
+    (a,) = _random_packs(params, n=1, seed=32)
+    host = sorted(a.entries)[0]
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    mixed = jax.tree_util.tree_unflatten(treedef, [
+        np.asarray(x) if M.path_str(p) == host else x for p, x in flat])
+    eng = core.SwitchEngine(mixed)
+    tr = trace.install()
+    try:
+        eng.load(a)
+    finally:
+        trace.uninstall()
+    counts = {e["name"]: e["args"]["value"] for e in tr.events()
+              if e["ph"] == "C"}
+    assert counts == {"switch.kernel_leaves": len(a.entries) - 1,
+                      "switch.fallback_leaves": 1}
+    want = core.apply_pack(params, a)
+    for x, y in zip(jax.tree.leaves(eng.params), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6)
+
+
 def test_fuse_packs_merges_duplicate_coordinates(setup):
     """Two packs sharing coordinates (same mask) must merge by ADDITION in
     the fused pack: loading it == loading both sequentially."""

@@ -5,77 +5,132 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import masks as M
 from repro.kernels import ops, ref
 from sidedelta_tables import sidedelta as raw_sidedelta
 
 
-@pytest.mark.parametrize("shape,bn,bm", [
-    ((256, 256), 128, 128),
-    ((512, 768), 256, 256),
-    ((384, 512), 128, 256),
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def _updates(rng, shape, k, dup=True):
+    """(idx, vals) of k entries per matrix of a (..., n, m) leaf, in random
+    order; with ``dup`` drawn with replacement (duplicates must sum)."""
+    *lead, n, m = shape
+    rows = int(np.prod(lead, dtype=np.int64))
+    if dup:
+        idx = rng.integers(0, n * m, (rows, k))
+    else:
+        idx = np.stack([rng.permutation(n * m)[:k] for _ in range(rows)])
+    vals = rng.standard_normal((rows, k)).astype(np.float32)
+    return (jnp.asarray(idx.reshape(tuple(lead) + (k,)), jnp.int32),
+            jnp.asarray(vals.reshape(tuple(lead) + (k,))))
+
+
+# shape, entries per matrix, duplicates, streams, weight dtype
+SCATTER_CASES = {
+    "square": ((256, 256), 1300, False, 1, jnp.float32),
+    "edges_200x576": ((200, 576), 2300, True, 1, jnp.float32),
+    "dense_duplicates": ((64, 200), 6000, True, 1, jnp.float32),
+    "layers": ((3, 136, 300), 800, True, 1, jnp.float32),
+    "experts": ((2, 3, 130, 260), 700, True, 1, jnp.float32),
+    "switch_unload_load": ((2, 256, 1100), 5000, True, 2, jnp.float32),
+    "bf16_weights": ((128, 384), 900, True, 1, jnp.bfloat16),
+}
+
+
 @pytest.mark.parametrize("alpha", [1.0, -1.0, 0.5])
-def test_scatter_apply(shape, bn, bm, dtype, alpha):
-    rng = np.random.RandomState(hash((shape, str(dtype))) % 2**31)
-    n, m = shape
-    k = max(int(0.02 * n * m), 4)
-    w = jnp.asarray(rng.randn(n, m), dtype)
-    idx = np.unique(rng.randint(0, n * m, 2 * k))[:k]
-    vals = rng.randn(len(idx)).astype(np.float32)
-    counts, rows, cols, vbuf = ops.bucket_updates(idx, vals, n, m, bn=bn, bm=bm)
-    out = ops.scatter_apply(w, jnp.asarray(counts), jnp.asarray(rows),
-                            jnp.asarray(cols), jnp.asarray(vbuf), alpha,
-                            bn=bn, bm=bm, interpret=True)
-    want = ref.scatter_apply_ref(w, jnp.asarray(idx), jnp.asarray(vals), alpha)
-    tol = 1e-6 if dtype == jnp.float32 else 3e-2
+@pytest.mark.parametrize("case", list(SCATTER_CASES))
+def test_scatter_apply(case, alpha):
+    """The one-hot tile kernel (interpret mode) against XLA's scatter
+    (``scatter_packed_add``), stream by stream: unsorted indices,
+    duplicates, partial edge blocks, (L, n, m) and (L, E, n, m) leaves,
+    both signs, and an unload fused with a load against the two in turn,
+    to f32 rounding."""
+    shape, k, dup, streams, dtype = SCATTER_CASES[case]
+    rng = np.random.default_rng(abs(hash((case, alpha))) % 2**31)
+    w = jnp.asarray(rng.standard_normal(shape), dtype)
+    ups = [_updates(rng, shape, k, dup) for _ in range(streams)]
+    scales = [alpha * (-1) ** (streams - 1 - s) for s in range(streams)]
+    out = ops.scatter_apply(w, ups, scales, interpret=True)
+    want = w.astype(jnp.float32)
+    for (idx, vals), a in zip(ups, scales):
+        want = M.scatter_packed_add(want, idx, vals, alpha=a)
+    if dtype == jnp.bfloat16:
+        want = want.astype(dtype)
+    tol = (8 * np.finfo(np.float32).eps * float(jnp.max(jnp.abs(want)))
+           if dtype == jnp.float32 else 3e-2)
+    assert out.shape == w.shape and out.dtype == w.dtype
     np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+                               np.asarray(want, np.float32), atol=tol, rtol=0)
 
 
-def test_scatter_apply_empty_tiles_untouched():
-    """Struct-style masks: tiles without updates must be bit-identical."""
-    rng = np.random.RandomState(0)
-    n, m, bn, bm = 512, 512, 256, 256
-    w = jnp.asarray(rng.randn(n, m), jnp.float32)
-    # updates only in the top-left tile
-    idx = (rng.randint(0, bn, 50) * m + rng.randint(0, bm, 50)).astype(np.int64)
-    idx = np.unique(idx)
-    vals = rng.randn(len(idx)).astype(np.float32)
-    counts, rows, cols, vbuf = ops.bucket_updates(idx, vals, n, m, bn=bn, bm=bm)
-    assert counts[0, 0] == len(idx) and counts[1, 1] == 0
-    out = ops.scatter_apply(w, jnp.asarray(counts), jnp.asarray(rows),
-                            jnp.asarray(cols), jnp.asarray(vbuf), 1.0,
-                            bn=bn, bm=bm, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out[bn:, bm:]),
-                                  np.asarray(w[bn:, bm:]))
+def test_scatter_apply_empty_blocks_untouched():
+    """Blocks no entry falls in come back bit-identical, and a load then
+    an unload of the same entries restores the weight to f32 rounding."""
+    rng = np.random.default_rng(0)
+    n, m = 512, 640
+    w = jnp.asarray(rng.standard_normal((n, m)), jnp.float32)
+    # entries only in the block at (128, 256), in random order
+    r, c = rng.integers(0, 128, 300) + 128, rng.integers(0, 128, 300) + 256
+    upd = (jnp.asarray(r * m + c, jnp.int32),
+           jnp.asarray(rng.standard_normal(300), jnp.float32))
+    out = np.asarray(ops.scatter_apply(w, [upd], [1.0], interpret=True))
+    hit = np.zeros((n, m), bool)
+    hit[128:256, 256:384] = True
+    np.testing.assert_array_equal(out[~hit], np.asarray(w)[~hit])
+    assert np.any(out[hit] != np.asarray(w)[hit])
+    back = ops.scatter_apply(jnp.asarray(out), [upd], [-1.0], interpret=True)
+    np.testing.assert_allclose(np.asarray(back), np.asarray(w), atol=1e-5)
 
 
-def test_scatter_load_unload_roundtrip():
-    rng = np.random.RandomState(1)
-    n = m = 512
-    w = jnp.asarray(rng.randn(n, m), jnp.float32)
-    idx = np.unique(rng.randint(0, n * m, 4000))
-    vals = rng.randn(len(idx)).astype(np.float32)
-    args = [jnp.asarray(a) for a in ops.bucket_updates(idx, vals, n, m)]
-    loaded = ops.scatter_apply(w, *args, 1.0, interpret=True)
-    restored = ops.scatter_apply(loaded, *args, -1.0, interpret=True)
-    np.testing.assert_allclose(np.asarray(restored), np.asarray(w), atol=1e-5)
+def test_scatter_apply_bucket_layout():
+    """``bucket``: the rows hold every entry once, sorted by block, and
+    each tile's bounds bracket exactly its blocks' entries."""
+    from repro.kernels.scatter_apply import bucket, grid_of
+    rng = np.random.default_rng(3)
+    shape = (2, 300, 700)
+    idx, vals_in = _updates(rng, shape, 1500)
+    lay = bucket(idx, vals_in, shape)
+    B, n, m, q, nt = grid_of(shape)
+    nbj = -(-m // 128)
+    flat_i = np.asarray(idx).reshape(B, -1)
+    for b in range(B):
+        r, c = np.divmod(flat_i[b], m)
+        want_blocks = np.sort((r // 128) * nbj + c // 128)
+        want_place = (r % 128) * 128 + c % 128
+        got = np.asarray(lay.entries[0]).reshape(-1)[b * 1500:(b + 1) * 1500]
+        assert sorted(got) == sorted(want_place)
+        bounds = np.asarray(lay.bounds).reshape(B, -1, nt, q + 1)[b]
+        for i in range(bounds.shape[0]):
+            for t in range(nt):
+                for j in range(q):
+                    blk = i * nbj + min(t * q + j, nbj)
+                    s = bounds[i, t, j] - b * 1500
+                    e = bounds[i, t, j + 1] - b * 1500
+                    if t * q + j < nbj:
+                        assert np.sum(want_blocks == blk) == e - s
+    vals = np.asarray(lay.entries[1]).view(np.float32).reshape(-1)
+    assert sorted(vals[:B * 1500]) == sorted(np.asarray(vals_in).reshape(-1))
+    assert not vals[B * 1500:].any()
 
 
-def test_scatter_apply_vs_dense_np_reference():
-    """Interpret-mode kernel vs a plain numpy dense scatter-add."""
-    rng = np.random.RandomState(7)
-    n, m = 512, 512
-    w = rng.randn(n, m).astype(np.float32)
-    idx = np.unique(rng.randint(0, n * m, 3000))
-    vals = rng.randn(len(idx)).astype(np.float32)
-    alpha = 0.75
-    args = [jnp.asarray(a) for a in ops.bucket_updates(idx, vals, n, m)]
-    out = ops.scatter_apply(jnp.asarray(w), *args, alpha, interpret=True)
-    want = w.copy()
-    want.reshape(-1)[idx] += alpha * vals
-    np.testing.assert_allclose(np.asarray(out), want, atol=1e-6)
+def test_scatter_apply_large_tile_overflows_window():
+    """A tile with many more entries than its window (a dense block among
+    sparse ones) is walked window after window."""
+    rng = np.random.default_rng(5)
+    n, m = 1024, 1024
+    w = jnp.asarray(rng.standard_normal((n, m)), jnp.float32)
+    sparse = rng.integers(0, n * m, 400)
+    dense = rng.integers(0, 128, 9000) * m + rng.integers(0, 128, 9000)
+    idx = np.concatenate([sparse, dense])
+    rng.shuffle(idx)
+    upd = (jnp.asarray(idx, jnp.int32),
+           jnp.asarray(rng.standard_normal(idx.size), jnp.float32))
+    from repro.kernels.scatter_apply import bucket, window_rows
+    assert window_rows((n, m), idx.size) * 128 < 9000 / 2
+    out = ops.scatter_apply(w, [upd], [0.25], interpret=True)
+    want = M.scatter_packed_add(w, *upd, alpha=0.25)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=8 * np.finfo(np.float32).eps
+                               * float(jnp.max(jnp.abs(want))), rtol=0)
 
 
 @pytest.mark.parametrize("B,S,n,m,A,K", [

@@ -487,6 +487,8 @@ def test_profiler_session_records_program_spans(paged_setup, tmp_path):
     engine.run()                                   # compile off the record
     sw = core.SwitchEngine(params)
     sw.load(packs[0])
+    sw.switch(packs[1])                 # compile the switch off the record
+    sw.switch(packs[0])
     jax.profiler.start_trace(str(tmp_path))
     try:
         engine.submit(prompt, packs[1].name, max_tokens=3)
@@ -509,11 +511,16 @@ def test_profiler_session_records_program_spans(paged_setup, tmp_path):
                for d in decodes)
     assert any(e[2].get("step") for e in steps)    # span.set() reaches it
     (switch,) = ev["repro.switch"]
-    for name in ("repro.switch.unload", "repro.switch.load",
+    # the unload and the load are one program, dispatched in switch.load
+    for name in ("repro.switch.load", "repro.switch.bucket",
                  "repro.switch.wait"):
         assert len(ev[name]) == 1 and _inside(ev[name][0], [switch])
+    assert _inside(ev["repro.switch.bucket"][0], ev["repro.switch.load"])
+    assert "repro.switch.unload" not in ev
+    assert ev["repro.switch.wait"][0][2]["chunks"] > 0
     assert {e["name"] for e in tr.events()} == {
-        "switch", "switch.unload", "switch.load", "switch.wait"}
+        "switch", "switch.load", "switch.bucket", "switch.wait",
+        "switch.kernel_leaves", "switch.fallback_leaves"}
     assert any(e[2].get("generation") == 2 for e in ev["repro.gc"])
     assert {e[2].get("stage") for e in ev["repro.compile"]} == {
         "lower", "backend"}

@@ -4,8 +4,9 @@ Nothing runs: the TPU compiler that ships with jaxlib compiles for a
 topology that is described, not attached, so what Mosaic would refuse on
 the chip (misaligned blocks, VMEM over-use) fails here at no chip time.
 The widths are starcoder2-7b's published ones (d_model 4608, d_ff 18432),
-plus deepseek-coder-33b's gated FFN (7168 x 19200), with SHiRA packs at 2%
-density, as ``launch/serve.py`` makes them. The side-delta tables have the
+plus deepseek-coder-33b's gated FFN (7168 x 19200) and deepseek-v2-lite's
+MLA and expert leaves, with SHiRA packs at 2% density, as
+``launch/serve.py`` makes them. The side-delta tables have the
 shapes ``sidedelta_table`` gives a random mask of that density.
 
 The topology is described inside a fixture, never at import: only one
@@ -71,13 +72,13 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _compile_for_tpu(fn, *args):
+def _compile_for_tpu(fn, *args, tpu_kernel=True):
     """Lower ``fn`` as it would trace on a TPU host: kernels that pick
     their path from ``jax.default_backend()`` take the Mosaic branch."""
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         lowered = jax.jit(fn).lower(*args)
     compiled = lowered.compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert ("tpu_custom_call" in compiled.as_text()) == tpu_kernel
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
@@ -202,3 +203,64 @@ def test_moe_gmm_gradient_compiles_for_v5e(one_chip):
         return jax.grad(loss, argnums=(0, 1))(p, x)
 
     _compile_for_tpu(fn, params, spec((2 * 1024, cfg.d_model), jnp.bfloat16))
+
+
+# The switch's adapted leaves: deepseek-coder-33b's w_up (2 layers, as the
+# benchmark cuts it), deepseek-v2-lite's MLA latent projection w_dkv (576 =
+# 4.5 blocks of 128), its dense layer-0 MLP (10944 = 85.5 blocks) and a moe
+# stage's expert stack (6 layers of 8 held experts).
+SWITCH_LEAVES = {
+    "dsc33_w_up": (2, D33, F33),
+    "dsv2l_w_dkv": (7, DSV2.d_model, 576),
+    "dsv2l_layer0_mlp": (1, DSV2.d_model, 10944),
+    "dsv2l_experts": (6, 8, DSV2.d_model, 1408),
+}
+
+
+def _switch_specs(one_chip, shape):
+    """(idx, vals) of a 2% pack for a leaf, as shapes on the chip."""
+    *lead, n, m = shape
+    k = (tuple(lead) + (_nnz(n, m),))
+    return (jax.ShapeDtypeStruct(k, jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct(k, jnp.float32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("leaf", list(SWITCH_LEAVES))
+def test_scatter_apply_compiles_for_v5e(one_chip, leaf):
+    """The switch's tile kernel at the benchmark's leaf shapes: an unload
+    fused with a load, the weight donated and updated in place, with no
+    XLA scatter or sort left in the program."""
+    from repro.kernels.scatter_apply import Layout, bucket, scatter_apply
+    shape = SWITCH_LEAVES[leaf]
+    lay = jax.eval_shape(lambda i, v: bucket(i, v, shape),
+                         *_switch_specs(one_chip, shape))
+    lay = Layout(*(jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+                   for x in lay))
+    w = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def fn(w, out, inc):
+        return scatter_apply(w, [out, inc], jnp.asarray([-1.0, 1.0]))
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = jax.jit(fn, donate_argnums=0).lower(w, lay, lay).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"%scatter_apply(\.\d+)* = [^\n]*custom-call\(", hlo)
+    assert not re.search(r" (scatter|sort)\(", hlo)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * int(np.prod(shape))   # in place
+    assert mem.temp_size_in_bytes < 4 * int(np.prod(shape)) // 8
+
+
+def test_switch_bucketing_compiles_for_v5e(one_chip):
+    """The bucketing program of a pack over the four leaves: one sort per
+    leaf, and no scatter."""
+    from repro.kernels.scatter_apply import bucket
+    shapes = list(SWITCH_LEAVES.values())
+    args = [_switch_specs(one_chip, s) for s in shapes]
+
+    def fn(entries):
+        return [bucket(i, v, s) for (i, v), s in zip(entries, shapes)]
+
+    hlo = _compile_for_tpu(fn, args, tpu_kernel=False).as_text()
+    assert len(re.findall(r" sort\(", hlo)) == len(shapes)
+    assert not re.search(r" scatter\(", hlo)
