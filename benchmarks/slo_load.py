@@ -48,9 +48,7 @@ for the measured (async) run, writes the JSONL + Chrome exports, prints
 the replay cost model's wall-time attribution and the
 ``replay.verify_overlap()`` check — how much of the predicted
 disk-load/table-build hiding the pipeline actually realized (CI gates
-this via ``benchmarks/check_replay.py``). ``--plan-cache PATH``
-installs an autotuned sidedelta tile-plan cache (``repro.analysis
-.autotune``) before the engines compile.
+this via ``benchmarks/check_replay.py``).
 
   PYTHONPATH=src python benchmarks/slo_load.py --smoke --json \
       --trace TRACE_slo_load.jsonl
@@ -64,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 import _emit
-from repro.analysis import autotune, replay, trace
+from repro.analysis import replay, trace
 from repro.configs import get_config, get_smoke_config
 from repro.hub import AdapterStore, PagedServingEngine
 from repro.launch.serve import make_adapters
@@ -135,10 +133,6 @@ def main() -> None:
                     help="write the serving trace of the async run "
                     "(JSONL) and print replay attribution + overlap "
                     "verification")
-    ap.add_argument("--plan-cache", nargs="?", const="benchmarks/"
-                    "plan_cache.json", default=None, metavar="PATH",
-                    help="install an autotuned sidedelta plan cache "
-                    "before compiling")
     ap.add_argument("--json", nargs="?", const="", default=None,
                     metavar="PATH",
                     help="write BENCH_slo_load.json (or PATH) with the "
@@ -147,12 +141,6 @@ def main() -> None:
     if not 0 < args.hot < args.adapters:
         raise SystemExit("need 0 < --hot < --adapters: the bench measures "
                          "warm AND cold admissions")
-
-    installed = 0
-    if args.plan_cache is not None:
-        installed = autotune.maybe_install_file(args.plan_cache)
-        print(f"plan cache: {installed} plans installed "
-              f"from {args.plan_cache}")
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     prompt_hi = 12
@@ -272,11 +260,6 @@ def main() -> None:
           f"(hit rate {hit_rate:.1%}); table builds: "
           f"{mt.async_builds} kicked, {mt.async_adopted} adopted, "
           f"{mt.async_stale} stale")
-    if installed:
-        from repro.kernels.sidedelta import plan_cache_stats
-        print(f"plan cache: {plan_cache_stats['hits']} hits, "
-              f"{plan_cache_stats['misses']} misses, "
-              f"{plan_cache_stats['rejected']} rejected")
 
     assert rep.completed == rep.offered, \
         f"dropped requests: {rep.completed}/{rep.offered}"
@@ -348,7 +331,6 @@ def main() -> None:
             "async_adopted": mt.async_adopted,
             "prefix_hits": engine.pool.prefix_hits,
             "cow_copies": engine.pool.cow_copies,
-            "plan_cache_plans": installed,
         }
         if realized is not None:
             metrics["overlap_realized_frac"] = realized

@@ -19,8 +19,8 @@ peak bytes in use so far:
   (f) agreement: f32 decode logits of the switched (fused-into-weights)
       path against the side-delta path, same adapter and prompt;
   (g) the compiled decode step of (d) holds the Mosaic kernel
-      (``tpu_custom_call``), so neither the Pallas interpreter nor the XLA
-      twin of ``sidedelta`` served on the chip.
+      (``tpu_custom_call``), so neither the Pallas interpreter nor the
+      plain jnp reference of ``sidedelta`` served on the chip.
 
 Without a TPU the script exits nonzero before any phase; any phase that
 fails exits nonzero. On success the last line of standard output is one
@@ -251,7 +251,7 @@ def main() -> int:
     cfg = get_config("starcoder2-7b").replace(num_layers=LAYERS)
     t0 = time.perf_counter()
     decode_text = run(cfg)
-    # (g) the Mosaic kernel, not the interpreter or the XLA twin
+    # (g) the Mosaic kernel, not the interpreter or the jnp reference
     if "tpu_custom_call" not in decode_text:
         raise AssertionError("the multi-tenant decode step holds no "
                              "tpu_custom_call: sidedelta did not compile "

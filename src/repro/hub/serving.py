@@ -607,7 +607,6 @@ class ServingEngine(_EngineCommon):
     def __init__(self, cfg, params, *, slots: int = 4, cache_size: int = 128,
                  scheduler: Optional[FusedLRU] = None, store=None,
                  table_dtype: str = "f32",
-                 interpret: Optional[bool] = None,
                  async_prefetch: bool = False, slot_pad: int = 1,
                  max_queue: Optional[int] = None,
                  fallback: str = "previous", nan_guard: bool = False):
@@ -619,7 +618,6 @@ class ServingEngine(_EngineCommon):
         self.cache_size = cache_size
         self.engine = MultiTenantEngine(cfg, params, scheduler=scheduler,
                                         store=store, table_dtype=table_dtype,
-                                        interpret=interpret,
                                         slot_pad=slot_pad)
         self.caches = lm.init_cache(cfg, slots, cache_size)
         self._axes = lm.cache_batch_axes(cfg)
@@ -841,7 +839,6 @@ class PagedServingEngine(_EngineCommon):
                  chunk_size: Optional[int] = None,
                  scheduler: Optional[FusedLRU] = None, store=None,
                  table_dtype: str = "f32", quant_kv: bool = False,
-                 interpret: Optional[bool] = None,
                  async_prefetch: bool = False, slot_pad: int = 1,
                  max_queue: Optional[int] = None,
                  fallback: str = "previous", nan_guard: bool = False):
@@ -858,7 +855,6 @@ class PagedServingEngine(_EngineCommon):
         self.chunk_size = chunk_size or page_size
         self.engine = MultiTenantEngine(cfg, params, scheduler=scheduler,
                                         store=store, table_dtype=table_dtype,
-                                        interpret=interpret,
                                         slot_pad=slot_pad)
         self.pool = PagePool(num_pages, page_size)
         self.caches = lm.init_paged_cache(cfg, num_pages, page_size,
@@ -879,16 +875,11 @@ class PagedServingEngine(_EngineCommon):
         self.peak_used_pages = 0      # incl. evictable registry-only pages
         self.peak_ws_pages = 0        # pages pinned by admitted requests
 
-        from repro.models import layers as L
-
         def _dec(p, t, c, pos, bt):
-            with L.sidedelta_backend(interpret):
-                return lm.decode_step(p, self.cfg, t, c, pos, block_tables=bt)
+            return lm.decode_step(p, self.cfg, t, c, pos, block_tables=bt)
 
         def _pfc(p, toks, c, bt, start, valid):
-            with L.sidedelta_backend(interpret):
-                return lm.prefill_chunk(p, self.cfg, toks, c, bt, start,
-                                        valid)
+            return lm.prefill_chunk(p, self.cfg, toks, c, bt, start, valid)
 
         self._decode = jax.jit(_dec)
         self._prefill_chunk = jax.jit(_pfc)

@@ -47,15 +47,15 @@ def quantize_table(vals: np.ndarray) -> Tuple[np.ndarray, float]:
     return q, scale
 
 
-@functools.partial(jax.jit, static_argnames=("m", "interpret"))
-def sidedelta(x, rows, cols, vals, ids, *, m, blocks=None, scale=None,
-              interpret=False):
+@functools.partial(jax.jit, static_argnames=("m",))
+def sidedelta(x, rows, cols, vals, ids, *, m, blocks=None, scale=None):
     """Batched per-request sparse delta: (B, S, m) f32 with
     delta[b] = x[b] @ dW_{ids[b]} (ids[b] < 0 -> zeros). ``vals`` may be
-    int8 with per-adapter ``scale`` (dequantised inside the kernel);
-    ``blocks`` comes with tables laid out by ``sidedelta_table``."""
+    int8 with per-adapter ``scale``. Tables laid out by ``sidedelta_table``
+    come with ``blocks`` and run the kernel; tables without them run the
+    plain jnp reference (``sidedelta_rows``)."""
     return sidedelta_rows(x, rows, cols, vals, ids, m, blocks=blocks,
-                          scale=scale, interpret=interpret)
+                          scale=scale)
 
 
 # ---------------------------------------------------------------------------

@@ -10,17 +10,19 @@ def sidedelta_ref(x: jax.Array, rows: jax.Array, cols: jax.Array,
                   vals: jax.Array, ids: jax.Array, m: int) -> jax.Array:
     """x: (B, S, n); rows/cols/vals: (A, K); ids: (B,) with -1 = no adapter.
     Returns (B, S, m) f32: delta[b] = x[b] @ dW_{ids[b]} with dW the sparse
-    matrix scattered from the packed (row, col, val) triples."""
-    B, S, n = x.shape
-    A, K = rows.shape
-
-    def one_adapter(r, c, v):
-        dw = jnp.zeros((n, m), jnp.float32)
-        return dw.at[r, c].add(v.astype(jnp.float32))
-
-    dense = jax.vmap(one_adapter)(rows, cols, vals)        # (A, n, m)
+    matrix scattered from the packed (row, col, val) triples (duplicate
+    pairs sum). dW is never built: each request gathers its inputs at its
+    adapter's rows, scales them by the values and scatter-adds them into
+    its columns, in O(B * S * K) memory. Plain jnp, so differentiable."""
+    S = x.shape[1]
     slot = jnp.maximum(ids, 0)
-    delta = jnp.einsum("bsn,bnm->bsm", x.astype(jnp.float32), dense[slot])
+
+    def one_request(xb, r, c, v):
+        xs = xb.astype(jnp.float32)[:, r] * v.astype(jnp.float32)
+        return jnp.zeros((S, m), jnp.float32).at[:, c].add(xs)
+
+    delta = jax.vmap(one_request)(x, rows[slot].astype(jnp.int32),
+                                  cols[slot].astype(jnp.int32), vals[slot])
     return jnp.where((ids >= 0)[:, None, None], delta, 0.0)
 
 
@@ -29,7 +31,7 @@ def sidedelta_int8_ref(x: jax.Array, rows: jax.Array, cols: jax.Array,
                        m: int) -> jax.Array:
     """int8-table oracle: vals_q (A, K) int8 with per-adapter scale (A,)
     f32 dequantized exactly as the kernel does (q * scale in f32) before
-    the dense reference contraction."""
+    the reference contraction."""
     vals = vals_q.astype(jnp.float32) * scale[:, None].astype(jnp.float32)
     return sidedelta_ref(x, rows.astype(jnp.int32), cols.astype(jnp.int32),
                          vals, ids, m)

@@ -25,8 +25,8 @@ Table layout (``sidedelta_table``, built once per table build on the host):
     ``n0 << 16 | m0``; -1 marks the padding after an adapter's last
     sub-chunk (adapters have different counts, the table has one width).
   * ``rows``/``cols`` stay global indices, so any consumer that ignores
-    the order (``kernels/ref.py``, the XLA twin below, the trainer) reads
-    the same tables unchanged.
+    the order (``kernels/ref.py``, the trainer) reads the same tables
+    unchanged.
 
 Kernel:
 
@@ -61,12 +61,11 @@ Kernel:
     one block (nothing is fetched again) and the kernel leaves the zeroed
     output block.
 
-Backends: ``interpret=True`` runs the Pallas interpreter (kernel-body
-emulation, any backend). ``interpret=False`` compiles — through Mosaic on
-TPU, and elsewhere (jax has no compiled Pallas lowering for the CPU)
-through ``_sidedelta_xla``, an XLA formulation that ignores the table
-order. ``interpret="xla"`` forces the twin everywhere (it is
-differentiable w.r.t. ``vals``, which the trainer needs).
+Dispatch follows from the tables: ``sidedelta_rows`` runs this kernel on
+tables that carry ``blocks`` (through Mosaic on TPU, the Pallas
+interpreter elsewhere), and ``kernels/ref.py``'s plain jnp formulation on
+tables that do not. The latter reads tables in any order and is
+differentiable w.r.t. ``vals``, which the multi-adapter trainer needs.
 
 The delta accumulates in f32 regardless of the compute dtype (the caller
 adds it onto the base matmul's output), so batched multi-tenant serving
@@ -77,13 +76,15 @@ from __future__ import annotations
 import functools
 import os
 from concurrent.futures import ThreadPoolExecutor
-from typing import Dict, NamedTuple, Optional, Sequence, Tuple
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ref import sidedelta_int8_ref, sidedelta_ref
 
 # Default VMEM byte budget for one program's working set. TPU v5e and v6e
 # cores have 128 MiB of VMEM; the plan keeps a quarter of it, and the
@@ -97,8 +98,6 @@ _BLOCK = _LANE       # rows and columns of one table block
 _KCS = (128, 256, 384, 512)  # sub-chunk lengths ``plan_tiles`` weighs
 _GROUP_ENTRIES = 16384   # most table entries one grid step streams (G * kc)
 _MAX_GROUP = 64          # most sub-chunks one grid step unrolls
-_MAX_BM = 1024       # largest m-tile of the XLA twin
-_MAX_KC = 512        # largest K chunk of the XLA twin
 # Fixed cost of one sub-chunk (its SMEM read, address arithmetic and two
 # matmul issues), in one-hot elements: what a sub-chunk costs beyond its
 # 2 * 128 * kc one-hot elements. Fitted to decode times of the kernel at
@@ -116,29 +115,6 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-# ---------------------------------------------------------------------------
-# Autotuned plan cache. ``analysis/autotune.py`` sweeps kc candidates per
-# (n, m, K) shape class against measured step times and installs the
-# winners here; ``plan_tiles`` consults the cache before falling back to the
-# block-count heuristic. Cached plans are validated on lookup (alignment
-# AND the budget bound) so a stale or hand-edited cache can never produce
-# an over-budget or misaligned kernel — it just misses.
-# ---------------------------------------------------------------------------
-
-PlanKey = Tuple[int, int, int, int, int]     # n, m, K, budget, x itemsize
-
-_PLAN_CACHE: Dict[PlanKey, int] = {}
-plan_cache_stats = {"hits": 0, "misses": 0, "rejected": 0}
-
-
-def plan_cache_key(n: int, m: int, K: int,
-                   vmem_budget: int = DEFAULT_VMEM_BUDGET,
-                   x_itemsize: int = 4) -> PlanKey:
-    """One shape class = one cache entry; the budget and input itemsize are
-    part of the class (they change the feasible plan set)."""
-    return (int(n), int(m), int(K), int(vmem_budget), int(x_itemsize))
-
-
 def plan_is_valid(n: int, m: int, K: int, kc: int, *,
                   vmem_budget: int = DEFAULT_VMEM_BUDGET,
                   x_itemsize: int = 4) -> bool:
@@ -148,35 +124,6 @@ def plan_is_valid(n: int, m: int, K: int, kc: int, *,
         return False
     return vmem_estimate(_ANY_S, n, m, kc, x_itemsize=x_itemsize,
                          vmem_budget=vmem_budget) <= vmem_budget
-
-
-def install_plan_cache(plans: Dict[PlanKey, int],
-                       replace: bool = False) -> int:
-    """Merge autotuned plans into the cache; returns entries installed."""
-    global _PLAN_CACHE
-    if replace:
-        _PLAN_CACHE = {}
-    for key, kc in plans.items():
-        _PLAN_CACHE[tuple(int(x) for x in key)] = int(kc)
-    return len(_PLAN_CACHE)
-
-
-def clear_plan_cache() -> None:
-    _PLAN_CACHE.clear()
-    for k in plan_cache_stats:
-        plan_cache_stats[k] = 0
-
-
-def plan_cache() -> Dict[PlanKey, int]:
-    return dict(_PLAN_CACHE)
-
-
-def _tile(dim_pad: int, cap: int) -> int:
-    """Largest multiple of 128 that divides ``dim_pad`` and is <= ``cap``."""
-    t = min(cap, dim_pad) // _LANE * _LANE
-    while dim_pad % t:
-        t -= _LANE
-    return t
 
 
 def _group_cap(n: int, m: int, kc: int, *, vmem_budget: int,
@@ -272,19 +219,7 @@ def plan_tiles(n: int, m: int, K: int, *, counts=None,
     sub-chunk; the cheapest wins, the shorter on a tie. Raises
     ``ValueError`` when not even kc = 128 fits: a kernel over budget would
     be refused by the compiler, or run with less VMEM than its plan
-    assumed.
-
-    An autotuned plan cache (``install_plan_cache``) is consulted first;
-    invalid cached plans are rejected, never trusted."""
-    cached = _PLAN_CACHE.get(plan_cache_key(n, m, K, vmem_budget,
-                                            x_itemsize))
-    if cached is not None:
-        if plan_is_valid(n, m, K, cached, vmem_budget=vmem_budget,
-                         x_itemsize=x_itemsize):
-            plan_cache_stats["hits"] += 1
-            return int(cached)
-        plan_cache_stats["rejected"] += 1
-    plan_cache_stats["misses"] += 1
+    assumed."""
     if counts is None:
         nb = -(-max(n, 1) // _BLOCK) * -(-max(m, 1) // _BLOCK)
         per = max(K, 1) / nb
@@ -464,42 +399,6 @@ def _sidedelta_kernel(ids_ref, scale_ref, x_ref, rows_ref, cols_ref,
 
 
 # ---------------------------------------------------------------------------
-# Compiled non-TPU dispatch: an XLA formulation that ignores table order
-# ---------------------------------------------------------------------------
-
-def _sidedelta_xla(x: jax.Array, rows: jax.Array, cols: jax.Array,
-                   vals: jax.Array, scale: jax.Array, ids: jax.Array,
-                   m: int, bm: int, kc: int) -> jax.Array:
-    """XLA twin of the kernel: per-request gather once, then a sequential
-    map over m-tiles, each scatter-accumulating in kc-sized K chunks with a
-    local-column one-hot mask, so any table order (bucketed or not) gives
-    the same sums. Peak memory stays O(B*S*K + B*kc*bm) — dW is never
-    materialised."""
-    B, S, n = x.shape
-    K = rows.shape[-1]                                    # pre-padded to kc
-    slot = jnp.maximum(ids, 0)
-    r = rows[slot].astype(jnp.int32)                      # (B, K)
-    c = cols[slot].astype(jnp.int32)
-    v = vals[slot].astype(jnp.float32) * scale[slot][:, None]
-    xs = jax.vmap(lambda xb, rb: xb.astype(jnp.float32)[:, rb])(x, r)
-    xs = (xs * v[:, None, :]).reshape(B, S, K // kc, kc)
-    c = c.reshape(B, K // kc, kc)
-    mt = _round_up(m, bm) // bm
-
-    def tile(j):
-        def chunk(i):
-            local = c[:, i] - j * bm                      # (B, kc)
-            onehot = (local[..., None]
-                      == jnp.arange(bm)[None, None, :]).astype(jnp.float32)
-            return jnp.einsum("bsk,bkc->bsc", xs[:, :, i], onehot)
-        return jnp.sum(jax.lax.map(chunk, jnp.arange(K // kc)), axis=0)
-
-    out = jax.lax.map(tile, jnp.arange(mt))               # (mt, B, S, bm)
-    out = jnp.moveaxis(out, 0, 2).reshape(B, S, mt * bm)[..., :m]
-    return jnp.where((ids >= 0)[:, None, None], out, 0.0)
-
-
-# ---------------------------------------------------------------------------
 # Entry point
 # ---------------------------------------------------------------------------
 
@@ -507,7 +406,6 @@ def sidedelta_rows(x: jax.Array, rows: jax.Array, cols: jax.Array,
                    vals: jax.Array, ids: jax.Array, m: int,
                    *, blocks: Optional[jax.Array] = None,
                    scale: Optional[jax.Array] = None,
-                   interpret=False,
                    vmem_budget: int = DEFAULT_VMEM_BUDGET) -> jax.Array:
     """x: (B, S, n); rows/cols: (A, K) int32 (or int16) per-adapter
     coordinates into (n, m); vals: (A, K) f32 or int8;
@@ -517,33 +415,20 @@ def sidedelta_rows(x: jax.Array, rows: jax.Array, cols: jax.Array,
     ids: (B,) int32 adapter slot per request, -1 = base model.
     Returns delta (B, S, m) f32.
 
-    ``interpret`` selects the execution mode: ``False`` compiles (Pallas on
-    TPU, the XLA twin elsewhere), ``True`` runs the Pallas kernel in
-    interpret mode, and ``"xla"`` forces the XLA twin on every backend —
-    the twin is pure jnp and therefore differentiable w.r.t. ``vals``,
-    which is what the multi-adapter trainer's forward pass relies on. The
-    twin reads tables in any order; the Pallas kernel needs ``blocks``."""
+    Tables with ``blocks`` run the Pallas kernel: compiled through Mosaic
+    on TPU, in the Pallas interpreter elsewhere. Tables without them (the
+    trainer's trainable tables) run ``ref.sidedelta_ref``, plain jnp that
+    reads tables in any order and is differentiable w.r.t. ``vals``."""
+    if blocks is None:
+        if scale is None:
+            return sidedelta_ref(x, rows, cols, vals, ids, m)
+        return sidedelta_int8_ref(x, rows, cols, vals, scale, ids, m)
     B, S, n = x.shape
     A, K = rows.shape
     if scale is None:
         scale = jnp.ones((A,), jnp.float32)
     if K == 0:
         return jnp.zeros((B, S, m), jnp.float32)
-    if interpret == "xla" or (
-            not interpret and jax.default_backend() != "tpu"):
-        # jax has no compiled Pallas path off-TPU: the twin computes the
-        # same sums through XLA
-        kc = K // blocks.shape[-1] if blocks is not None else min(
-            _round_up(K, _LANE), _MAX_KC)
-        K_pad = _round_up(K, kc)
-        if K_pad != K:
-            pad = ((0, 0), (0, K_pad - K))
-            rows, cols, vals = (jnp.pad(t, pad) for t in (rows, cols, vals))
-        bm = _tile(_round_up(m, _LANE), _MAX_BM)
-        return _sidedelta_xla(x, rows, cols, vals, scale, ids, m, bm, kc)
-    if blocks is None:
-        raise ValueError("the sidedelta kernel reads block-bucketed tables: "
-                         "lay them out with sidedelta_table, pass blocks")
     C = blocks.shape[-1]
     kc = K // C
     if kc * C != K or kc % _LANE:
@@ -594,7 +479,7 @@ def sidedelta_rows(x: jax.Array, rows: jax.Array, cols: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_budget + _VMEM_HEADROOM),
-        interpret=interpret,
+        interpret=jax.default_backend() != "tpu",
         name="sidedelta",
     )(ids, scale, x, *(t.reshape(A, 1, K) for t in (rows, cols, vals)),
       blocks.reshape(A, C // group, 1, group))

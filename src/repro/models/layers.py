@@ -67,41 +67,15 @@ def cast_compute(tree):
 # with a per-adapter sparse-delta table and the batch's per-request adapter
 # ids (see repro/serving/multitenant.py). ``pdot`` then computes the base
 # matmul once for the whole batch plus each request's sparse correction via
-# the Pallas sidedelta kernel. The bundle is a plain dict so it survives
-# jax.lax.scan slicing over stacked layer weights. Tables may be quantized:
-# ``sd.vals`` int8 with a per-adapter ``sd.scale`` (dequantized inside the
-# kernel's VMEM, so the resident adapter tables stay ~4x smaller).
+# ``ops.sidedelta``. The bundle is a plain dict so it survives jax.lax.scan
+# slicing over stacked layer weights. The tables decide the formulation:
+# serving's block-bucketed tables carry ``sd.blocks`` and run the Pallas
+# kernel; the trainer's trainable tables carry none and run the plain,
+# differentiable jnp reference. Tables may be quantized: ``sd.vals`` int8
+# with a per-adapter ``sd.scale`` (dequantized inside the kernel's VMEM, so
+# the resident adapter tables stay ~4x smaller).
 
 SIDEDELTA_KEY = "sd.base"
-
-# Which execution mode the sidedelta kernel uses, read at TRACE time (same
-# discipline as compute_precision): None = auto (Pallas interpret emulation
-# off-TPU, compiled Mosaic on TPU); True/False force it. interpret=False
-# off-TPU compiles the XLA twin, which computes the same sums from the same
-# tables in any order. "xla"
-# forces the pure-jnp XLA twin on every backend; the twin is differentiable
-# w.r.t. the value tables, which the multi-adapter trainer's forward needs.
-SIDEDELTA_INTERPRET = None
-
-
-def sidedelta_interpret():
-    if SIDEDELTA_INTERPRET is None:
-        return jax.default_backend() != "tpu"
-    return SIDEDELTA_INTERPRET
-
-
-@contextlib.contextmanager
-def sidedelta_backend(interpret):
-    """Temporarily force the sidedelta kernel mode (True, False, or "xla").
-    Jitted closures must be *traced* inside the scope — the flag is read at
-    trace time."""
-    global SIDEDELTA_INTERPRET
-    prev = SIDEDELTA_INTERPRET
-    SIDEDELTA_INTERPRET = interpret
-    try:
-        yield
-    finally:
-        SIDEDELTA_INTERPRET = prev
 
 
 def sidedelta_weight(base: jax.Array, rows: jax.Array, cols: jax.Array,
@@ -111,8 +85,9 @@ def sidedelta_weight(base: jax.Array, rows: jax.Array, cols: jax.Array,
     """base: (n, m); rows/cols/vals: (A, K) packed per-adapter deltas
     (vals f32, or int8 with per-adapter ``scale`` (A,) f32);
     ids: (B,) int32 per-request adapter slot (-1 = base only);
-    blocks: (A, C) sub-chunk origins of block-bucketed tables (the Pallas
-    kernel needs them; the XLA twin reads tables in any order)."""
+    blocks: (A, C) sub-chunk origins of block-bucketed tables, which run
+    the Pallas kernel; without them the tables, in any order, run the
+    differentiable jnp reference."""
     w = {SIDEDELTA_KEY: base, "sd.rows": rows, "sd.cols": cols,
          "sd.vals": vals, "sd.ids": ids}
     if scale is not None:
@@ -163,8 +138,7 @@ def _pdot_sidedelta(x: jax.Array, w: dict) -> jax.Array:
     y = pdot(x, base)
     delta = sidedelta(x, w["sd.rows"], w["sd.cols"], w["sd.vals"],
                       w["sd.ids"], m=base.shape[-1],
-                      blocks=w.get("sd.blocks"), scale=w.get("sd.scale"),
-                      interpret=sidedelta_interpret())
+                      blocks=w.get("sd.blocks"), scale=w.get("sd.scale"))
     return (y.astype(jnp.float32) + delta).astype(y.dtype)
 
 

@@ -173,8 +173,7 @@ class MultiTenantEngine:
     accepts a registered adapter id instead of a pack object."""
 
     def __init__(self, cfg, params, *, scheduler: Optional[FusedLRU] = None,
-                 store=None, table_dtype: str = "f32",
-                 interpret: Optional[bool] = None, slot_pad: int = 1):
+                 store=None, table_dtype: str = "f32", slot_pad: int = 1):
         if table_dtype not in ("f32", "int8"):
             raise ValueError(f"table_dtype must be 'f32' or 'int8', got "
                              f"{table_dtype!r}")
@@ -186,7 +185,6 @@ class MultiTenantEngine:
         self.scheduler = scheduler
         self.store = store
         self.table_dtype = table_dtype       # device-table value dtype
-        self.interpret = interpret           # sidedelta mode (None = auto)
         # slot-capacity bucket: the tables' adapter axis is rounded up to a
         # multiple of this, so registering an adapter within the padded
         # capacity keeps every table shape constant — no prefill/decode
@@ -213,17 +211,11 @@ class MultiTenantEngine:
         self.async_adopted = 0               # adopted (saved a sync rebuild)
         self.async_stale = 0                 # discarded (state moved on)
 
-        # the sidedelta mode is read at trace time (layers.sidedelta_backend)
-        # — scope the traces so an engine-level override actually lands
-        from repro.models import layers as L
-
         def _prefill(p, b, cs):
-            with L.sidedelta_backend(interpret):
-                return lm.prefill(p, self.cfg, b, cs)
+            return lm.prefill(p, self.cfg, b, cs)
 
         def _decode(p, t, c, pos):
-            with L.sidedelta_backend(interpret):
-                return lm.decode_step(p, self.cfg, t, c, pos)
+            return lm.decode_step(p, self.cfg, t, c, pos)
 
         self._prefill = jax.jit(_prefill, static_argnums=2)
         self._decode = jax.jit(_decode)

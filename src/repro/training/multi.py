@@ -9,11 +9,11 @@ finetuning batches share every base-weight matmul:
   * the packed trainables are batched as ``(A, …, K)`` value trees;
   * the step batch is the concatenation of each adapter's batch, with an
     ``ids`` row->adapter routing vector; weight leaves become
-    ``sidedelta_weight`` bundles over the *trainable* value tables, run
-    through the differentiable XLA twin of the kernel
-    (``sidedelta_backend("xla")``) — the one-hot gather/scatter trick of
-    ``kernels/sidedelta.py``, now with autodiff giving the per-adapter
-    scatter-add gradient reduction for free;
+    ``sidedelta_weight`` bundles over the *trainable* value tables. They
+    carry no ``sd.blocks``, so ``pdot`` runs them through the plain jnp
+    side-delta (``kernels/ref.sidedelta_ref``: gather, scale, scatter-add),
+    and autodiff gives the per-adapter scatter-add gradient reduction for
+    free;
   * the loss is the SUM of per-adapter mean NLLs, so each adapter's value
     gradients are exactly what its own single-adapter run would produce
     (values only touch rows routed to them — no cross-terms);
@@ -50,7 +50,7 @@ from repro.analysis import trace
 from repro.configs.base import RunConfig
 from repro.data import TaskSpec, make_batch
 from repro.kernels import ops
-from repro.models import layers, lm
+from repro.models import lm
 from repro.models.layers import rms_norm, sidedelta_weight, unembed
 from repro.optim import batched_global_norm, lr_schedule
 from repro.runtime.trainer import TrainerConfig
@@ -179,7 +179,8 @@ class MultiAdapterTrainer:
     def _wrapped_params(self, values):
         """Base tree with target leaves replaced by sidedelta bundles over
         the TRAINABLE (A, …, K) value tables — gradients flow through the
-        bundle's ``sd.vals`` entry via the XLA twin."""
+        bundle's ``sd.vals`` entry via the plain jnp side-delta (the
+        bundles carry no ``sd.blocks``)."""
         none_leaf = lambda x: x is None
 
         def leaf(w, r, c, v):
@@ -277,10 +278,8 @@ class MultiAdapterTrainer:
             lr = self.schedule(state["step"])
 
             def loss_fn(values):
-                # trace-time flag: the XLA twin is the differentiable path
-                with layers.sidedelta_backend("xla"):
-                    losses, aux = self._per_adapter_loss(
-                        self._wrapped_params(values), batch)
+                losses, aux = self._per_adapter_loss(
+                    self._wrapped_params(values), batch)
                 loss = jnp.sum(losses)
                 if self.cfg.family == "moe" or (
                         self.cfg.moe and self.cfg.moe.num_experts):

@@ -146,7 +146,7 @@ def test_sidedelta_parity(B, S, n, m, A, K, dtype):
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int32)
     vals = jnp.asarray(rng.randn(A, K), jnp.float32)
     ids = jnp.asarray(rng.randint(-1, A, (B,)), jnp.int32)
-    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=True)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m)
     want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
@@ -161,8 +161,7 @@ def test_sidedelta_base_requests_untouched():
     cols = jnp.asarray(rng.randint(0, 16, (2, 11)), jnp.int32)
     vals = jnp.asarray(rng.randn(2, 11), jnp.float32)
     ids = jnp.asarray([-1, 0, -1], jnp.int32)
-    out = np.asarray(raw_sidedelta(x, rows, cols, vals, ids, m=16,
-                                   interpret=True))
+    out = np.asarray(raw_sidedelta(x, rows, cols, vals, ids, m=16))
     assert np.all(out[0] == 0) and np.all(out[2] == 0)
     assert np.any(out[1] != 0)
 
@@ -172,29 +171,29 @@ def test_sidedelta_base_requests_untouched():
     (2, 4, 96, 384, 2, 33, 128),      # MoE expert-ish: odd n, 3 blocks
     (2, 2, 160, 576, 2, 47, 256),     # MLA-ish: non-pow2 dims, ragged block
 ])
-@pytest.mark.parametrize("interpret", [True, False])
-def test_sidedelta_tiled_parity(B, S, n, m, A, K, kc, interpret):
-    """Multi-block tables must match the oracle in BOTH the Pallas
-    interpreter and compiled mode (on CPU the latter dispatches the XLA
-    twin, which reads the same tables in any order)."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sidedelta_tiled_parity(B, S, n, m, A, K, kc, dtype):
+    """Multi-block tables must match the oracle for f32 activations and
+    for the bf16 ones serving feeds; the oracle reads the same
+    bf16-rounded x in f32, and the interpreter's matmuls are exact at
+    both, so one tolerance holds."""
     rng = np.random.RandomState(hash((B, S, n, m, A, K)) % 2**31)
-    x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
+    x = jnp.asarray(rng.randn(B, S, n), dtype)
     rows = jnp.asarray(rng.randint(0, n, (A, K)), jnp.int32)
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int32)
     vals = jnp.asarray(rng.randn(A, K), jnp.float32)
     ids = jnp.asarray(rng.randint(-1, A, (B,)), jnp.int32)
-    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=interpret,
-                        kc=kc)
-    want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, kc=kc)
+    want = ref.sidedelta_ref(x.astype(jnp.float32), rows, cols, vals, ids,
+                             m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
                                atol=1e-5, rtol=1e-5)
 
 
-def test_sidedelta_compiled_big_dff():
-    """The acceptance shape: m=8192 with interpret=False on CPU. The plan
-    must be one of the weighed sub-chunk lengths and fit the VMEM budget
-    at this S, and the compiled result must match the oracle to fp32
-    accuracy."""
+def test_sidedelta_big_dff():
+    """The acceptance shape: m=8192. The plan must be one of the weighed
+    sub-chunk lengths and fit the VMEM budget at this S, and the kernel
+    must match the oracle to fp32 accuracy."""
     from repro.kernels.sidedelta import (_KCS, DEFAULT_VMEM_BUDGET,
                                          plan_tiles, vmem_estimate)
     B, S, n, m, A, K = 2, 8, 256, 8192, 2, 1024
@@ -207,14 +206,14 @@ def test_sidedelta_compiled_big_dff():
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int32)
     vals = jnp.asarray(rng.randn(A, K), jnp.float32)
     ids = jnp.asarray([1, -1], jnp.int32)
-    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=False)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m)
     want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
                                atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("interpret", [True, False])
-def test_sidedelta_tile_straddle(interpret):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sidedelta_tile_straddle(dtype):
     """Nonzeros ON the block boundaries (last col of block j, first col of
     block j+1) and duplicate (row, col) pairs must land exactly once each —
     the block's one-hot masks, it must not double-count or drop."""
@@ -222,27 +221,28 @@ def test_sidedelta_tile_straddle(interpret):
     rows = jnp.asarray([[0, 1, 2, 2, 3]], jnp.int32)
     cols = jnp.asarray([[127, 128, 255, 255, 0]], jnp.int32)  # edges + dup
     vals = jnp.asarray([[1.0, 2.0, 3.0, 4.0, 5.0]], jnp.float32)
-    x = jnp.asarray(np.random.RandomState(3).randn(1, 4, n), jnp.float32)
+    x = jnp.asarray(np.random.RandomState(3).randn(1, 4, n), dtype)
+    xf = x.astype(jnp.float32)
     ids = jnp.asarray([0], jnp.int32)
-    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, interpret=interpret,
-                        kc=128)
-    want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, kc=128)
+    want = ref.sidedelta_ref(xf, rows, cols, vals, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
     # the duplicate (2, 255) really accumulated 3 + 4
     np.testing.assert_allclose(np.asarray(out)[0, :, 255],
-                               np.asarray(x)[0, :, 2] * 7.0, rtol=1e-5)
+                               np.asarray(xf)[0, :, 2] * 7.0, rtol=1e-5)
 
 
-@pytest.mark.parametrize("interpret", [True, False])
-def test_sidedelta_int8_tables(interpret):
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sidedelta_int8_tables(dtype):
     """int8 vals + per-adapter scale + int16 indices: exact against the
     int8 oracle (dequant is the same f32 math), and within dequant
     tolerance (<1e-2) of the unquantized f32 reference at SHiRA-realistic
     value magnitudes."""
     rng = np.random.RandomState(5)
     B, S, n, m, A, K = 3, 8, 128, 512, 3, 200
-    x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
+    x = jnp.asarray(rng.randn(B, S, n), dtype)
+    xf = x.astype(jnp.float32)
     rows = jnp.asarray(rng.randint(0, n, (A, K)), jnp.int16)
     cols = jnp.asarray(rng.randint(0, m, (A, K)), jnp.int16)
     vf = (0.05 * rng.randn(A, K)).astype(np.float32)   # adapter-scale values
@@ -251,16 +251,87 @@ def test_sidedelta_int8_tables(interpret):
     scale = jnp.asarray(np.array([s for _, s in qs], np.float32))
     assert vq.dtype == jnp.int8
     ids = jnp.asarray([0, -1, 2], jnp.int32)
-    out = raw_sidedelta(x, rows, cols, vq, ids, m=m, scale=scale,
-                        interpret=interpret, kc=128)
-    want_q = ref.sidedelta_int8_ref(x, rows, cols, vq, scale, ids, m)
+    out = raw_sidedelta(x, rows, cols, vq, ids, m=m, scale=scale, kc=128)
+    want_q = ref.sidedelta_int8_ref(xf, rows, cols, vq, scale, ids, m)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want_q),
                                atol=1e-5, rtol=1e-5)
-    want_f = ref.sidedelta_ref(x, rows.astype(jnp.int32),
+    want_f = ref.sidedelta_ref(xf, rows.astype(jnp.int32),
                                cols.astype(jnp.int32), jnp.asarray(vf),
                                ids, m)
     assert float(np.max(np.abs(np.asarray(out) - np.asarray(want_f)))) < 1e-2
     assert np.all(np.asarray(out)[1] == 0)             # ids = -1 stays zero
+
+
+def _dense_delta(x, rows, cols, vals, ids, m):
+    """x[b] @ dW_{ids[b]} with each adapter's dW built dense (duplicate
+    (row, col) pairs sum), 0 where ids[b] < 0."""
+    A, n = rows.shape[0], x.shape[-1]
+    dw = jnp.zeros((A, n, m), jnp.float32).at[
+        jnp.arange(A)[:, None], rows, cols].add(vals)
+    out = jnp.einsum("bsn,bnm->bsm", x, dw[jnp.maximum(ids, 0)],
+                     precision=jax.lax.Precision.HIGHEST)
+    return jnp.where((ids >= 0)[:, None, None], out, 0.0)
+
+
+def _raw_tables(rng, B, n, m, A, K, dups=True):
+    """(rows, cols, vals, ids) drawn with duplicate (row, col) pairs."""
+    rows = rng.randint(0, n, (A, K))
+    cols = rng.randint(0, m, (A, K))
+    if dups and K > 1:
+        rows[:, 1], cols[:, 1] = rows[:, 0], cols[:, 0]
+    return (jnp.asarray(rows, jnp.int32), jnp.asarray(cols, jnp.int32),
+            jnp.asarray(rng.randn(A, K), jnp.float32),
+            jnp.asarray(np.r_[-1, rng.randint(0, A, B - 1)], jnp.int32))
+
+
+@pytest.mark.parametrize("B,S,n,m,A,K,int8", [
+    (3, 8, 128, 512, 3, 65, False),
+    (2, 4, 96, 384, 2, 33, False),
+    (2, 2, 160, 576, 2, 47, False),
+    (3, 8, 128, 512, 3, 200, True),    # int8 values, int16 indices
+    (2, 4, 64, 256, 2, 0, False),      # empty tables
+])
+def test_sidedelta_ref_matches_dense(B, S, n, m, A, K, int8):
+    """The reference (gather, scale, scatter-add; dW never built) equals
+    x @ dW with dW built dense, duplicates summed, base rows zero."""
+    rng = np.random.RandomState(hash((B, S, n, m, A, K)) % 2**31)
+    x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
+    rows, cols, vals, ids = _raw_tables(rng, B, n, m, A, K)
+    if int8:
+        q = [ops.quantize_table(np.asarray(v)) for v in vals]
+        vq = jnp.asarray(np.stack([a for a, _ in q]))
+        scale = jnp.asarray(np.array([s for _, s in q], np.float32))
+        got = ref.sidedelta_int8_ref(x, rows.astype(jnp.int16),
+                                     cols.astype(jnp.int16), vq, scale, ids,
+                                     m)
+        vals = vq.astype(jnp.float32) * scale[:, None]
+    else:
+        got = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
+    want = _dense_delta(x, rows, cols, vals, ids, m)
+    assert got.shape == (B, S, m) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    assert not np.asarray(got)[0].any()                  # ids = -1
+
+
+def test_sidedelta_ref_grad_matches_dense():
+    """The trainer differentiates the reference w.r.t. the value tables:
+    its gradient equals the dense form's, duplicates and base rows
+    included."""
+    rng = np.random.RandomState(11)
+    B, S, n, m, A, K = 4, 6, 96, 200, 3, 57
+    x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
+    rows, cols, vals, ids = _raw_tables(rng, B, n, m, A, K)
+    g = jnp.asarray(rng.randn(B, S, m), jnp.float32)
+
+    def loss(f):
+        return lambda v: jnp.sum(f(x, rows, cols, v, ids, m) * g)
+
+    got = jax.grad(loss(ref.sidedelta_ref))(vals)
+    want = jax.grad(loss(_dense_delta))(vals)
+    assert np.asarray(got).any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 def _mask_flat(kind, n, m, K, rng):
@@ -303,7 +374,7 @@ def test_sidedelta_bucketed_kernel_parity(mask, B, S, n, m, A, K, int8,
     out = sidedelta_rows(
         x, jnp.asarray(t.rows), jnp.asarray(t.cols), jnp.asarray(t.vals),
         ids, m, blocks=jnp.asarray(t.blocks),
-        scale=jnp.asarray(scale) if int8 else None, interpret=True,
+        scale=jnp.asarray(scale) if int8 else None,
         vmem_budget=budget or DEFAULT_VMEM_BUDGET)
     kmax = max(len(f) for f in flats)
     pad = lambda arrs, dt: np.stack([np.pad(a, (0, kmax - len(a))).astype(dt)

@@ -362,37 +362,39 @@ def test_int8_tables_skip_f32_roundtrip(dense_setup):
         np.asarray(t["scale"]).reshape(-1)[0], scale * qp.alpha)
 
 
-def test_forced_compiled_mode_on_cpu(dense_setup):
-    """interpret=False threaded through the engine -> pdot -> kernel must
-    serve correctly under JAX_PLATFORMS=cpu (jax compiles Pallas only for
-    accelerators, so this runs the kernel's XLA twin) and match the default
-    interpret-mode engine exactly."""
+@pytest.mark.parametrize("table_dtype", ["f32", "int8"])
+def test_sidedelta_dispatch(dense_setup, table_dtype):
+    """The tables pick the side-delta formulation: every bundle the engine
+    serves carries ``sd.blocks``, so serving always runs the kernel, and
+    ``pdot`` over the same bundle without them (the trainer's plain jnp
+    path) gives the same output."""
+    from repro.hub import AdapterStore
     with layers.compute_precision(jnp.float32):
         cfg, params, packs = dense_setup
-        default = MultiTenantEngine(cfg, params)
-        compiled = MultiTenantEngine(cfg, params, interpret=False)
+        store = AdapterStore()
         for p in packs:
-            default.register(p)
-            compiled.register(p)
-        B, S, T = 4, 8, 3
-        toks = jax.random.randint(jax.random.PRNGKey(9), (B, S), 0,
-                                  cfg.vocab_size)
-        names = ["a0", "a1", "a2", None]
-        want, _ = default.generate({"tokens": toks}, names, T)
-        got, _ = compiled.generate({"tokens": toks}, names, T)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_sidedelta_backend_context():
-    """layers.sidedelta_backend must set the trace-time flag and restore
-    the previous value on exit (incl. the auto default off-TPU)."""
-    assert layers.sidedelta_interpret() == (jax.default_backend() != "tpu")
-    with layers.sidedelta_backend(False):
-        assert layers.sidedelta_interpret() is False
-        with layers.sidedelta_backend(True):
-            assert layers.sidedelta_interpret() is True
-        assert layers.sidedelta_interpret() is False
-    assert layers.sidedelta_interpret() == (jax.default_backend() != "tpu")
+            store.add(p, values=table_dtype)
+        engine = MultiTenantEngine(cfg, params, store=store,
+                                   table_dtype=table_dtype)
+        for p in packs:
+            engine.register(p.name)
+        ids = engine.ids_for(["a0", None, "a2", "a1"])
+        bundles = [w for w in jax.tree.leaves(engine.wrapped_params(ids),
+                                              is_leaf=layers.is_sidedelta)
+                   if layers.is_sidedelta(w)]
+        assert bundles and all("sd.blocks" in w for w in bundles)
+        w = bundles[0]
+        while w[layers.SIDEDELTA_KEY].ndim > 2:   # one layer of a stack
+            w = {k: v[0] for k, v in w.items()}
+        assert ("sd.scale" in w) == (table_dtype == "int8")
+        plain = {k: v for k, v in w.items() if k != "sd.blocks"}
+        x = jax.random.normal(jax.random.PRNGKey(3),
+                              (4, 5, w[layers.SIDEDELTA_KEY].shape[0]))
+        kernel = layers.pdot(x, w)
+        np.testing.assert_allclose(np.asarray(layers.pdot(x, plain)),
+                                   np.asarray(kernel), atol=1e-5, rtol=1e-5)
+        assert float(jnp.max(jnp.abs(kernel - layers.pdot(
+            x, w[layers.SIDEDELTA_KEY])))) > 0    # the deltas are not zero
 
 
 def test_unsupported_target_rejected():

@@ -56,14 +56,13 @@ def test_scatter_set_then_add_inverse(n, m, seed, alpha):
 @given(B=st.integers(1, 4), S=st.integers(1, 8),
        n=st.integers(3, 160), m=st.integers(3, 520),
        K=st.integers(0, 300), seed=st.integers(0, 2 ** 16),
-       int8=st.booleans(), interpret=st.booleans())
+       int8=st.booleans())
 @settings(max_examples=25, deadline=None)
-def test_sidedelta_tiled_matches_ref(B, S, n, m, K, seed, int8, interpret):
-    """The tiled+vectorised sidedelta (interpret AND compiled dispatch)
-    must match ``sidedelta_ref`` for arbitrary (B, S, n, m, K): K=0,
-    all-base batches (ids=-1), nonzeros straddling block boundaries (the
-    blocks are 128 wide, so any m > 128 has several), and int8 tables
-    within dequant tolerance."""
+def test_sidedelta_tiled_matches_ref(B, S, n, m, K, seed, int8):
+    """The tiled+vectorised sidedelta kernel must match ``sidedelta_ref``
+    for arbitrary (B, S, n, m, K): K=0, all-base batches (ids=-1),
+    nonzeros straddling block boundaries (the blocks are 128 wide, so any
+    m > 128 has several), and int8 tables within dequant tolerance."""
     rng = np.random.RandomState(seed)
     A = rng.randint(1, 4)
     x = jnp.asarray(rng.randn(B, S, n), jnp.float32)
@@ -86,8 +85,7 @@ def test_sidedelta_tiled_matches_ref(B, S, n, m, K, seed, int8, interpret):
         vals, scale = jnp.asarray(vf), None
         want = ref.sidedelta_ref(x, rows, cols, vals, ids, m)
         tol = 1e-5
-    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, scale=scale,
-                        interpret=interpret, kc=128)
+    out = raw_sidedelta(x, rows, cols, vals, ids, m=m, scale=scale, kc=128)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
     if int8:   # and the dequant stays within the serving tolerance
